@@ -28,9 +28,8 @@ validated by CI's bench-smoke job); the returned CSV rows are the
 human-readable view of the same entries.
 
 Max-abs deviation between the two layouts is reported per shape (f32
-flash-vs-plain-softmax rounding on decode; 0.0 expected on prefill,
-where the in-place kernel replays the reference chunk walk — token- and
-byte-level parity is pinned in tests/test_paged_attn.py).
+flash-vs-plain-softmax rounding on decode and prefill alike; the
+tolerances are pinned in tests/test_paged_attn.py).
 """
 
 from __future__ import annotations
@@ -54,8 +53,8 @@ def _decode_setup(key, *, B, K, Hkv, Dk, Dv, bsz):
     P = B * K + 1
     ks = jax.random.split(key, 5)
     cache = A.PagedAttnCache(
-        k=jax.random.normal(ks[0], (P, bsz, Hkv, Dk), jnp.float32),
-        v=jax.random.normal(ks[1], (P, bsz, Hkv, Dv), jnp.float32),
+        k=jax.random.normal(ks[0], (P, Hkv, bsz, Dk), jnp.float32),
+        v=jax.random.normal(ks[1], (P, Hkv, bsz, Dv), jnp.float32),
         pos=jnp.asarray(
             np.arange(P * bsz).reshape(P, bsz) % (K * bsz), jnp.int32))
     rs = np.random.RandomState(0)
@@ -83,8 +82,8 @@ def _prefill_setup(key, *, B, K, Ts, Hkv, Dk, Dv, bsz):
     P = B * K + 1
     ks = jax.random.split(key, 6)
     cache = A.PagedAttnCache(
-        k=jax.random.normal(ks[0], (P, bsz, Hkv, Dk), jnp.float32),
-        v=jax.random.normal(ks[1], (P, bsz, Hkv, Dv), jnp.float32),
+        k=jax.random.normal(ks[0], (P, Hkv, bsz, Dk), jnp.float32),
+        v=jax.random.normal(ks[1], (P, Hkv, bsz, Dv), jnp.float32),
         pos=jnp.zeros((P, bsz), jnp.int32))
     table = np.zeros((B, K), np.int32)
     pos = np.full((P, bsz), -1, np.int32)

@@ -6,8 +6,8 @@ Prints CSV blocks per benchmark.  --full widens sweeps (slower).
 ``--suite paged_attn`` (or any registered name, with or without the
 ``_bench`` suffix) runs a single suite; ``--smoke`` shrinks it to tiny
 shapes and *validates the emitted JSON artifact* against the shared
-schema (``common.validate_bench_json``), exiting nonzero on any error —
-the CI bench-smoke job's contract.
+schema (``common.validate_bench_json``).  Any suite that raises makes
+the run exit nonzero, with or without ``--smoke``.
 
 The roofline/dry-run artifacts (deliverables e/g) are produced separately
 by ``python -m repro.launch.dryrun --all`` and summarised by
@@ -34,8 +34,7 @@ def main() -> None:
                     help="run a single suite by short name "
                          "(e.g. paged_attn)")
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny shapes; validate emitted JSON artifacts "
-                         "and exit nonzero on any failure")
+                    help="tiny shapes; validate emitted JSON artifacts")
     args = ap.parse_args()
     quick = not args.full
 
@@ -106,7 +105,7 @@ def main() -> None:
         for k, v in sorted(doms.items()):
             print(f"dominant_{k},{v}")
 
-    if args.smoke and failed:
+    if failed:
         sys.exit(1)
 
 

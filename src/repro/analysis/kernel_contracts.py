@@ -3,8 +3,12 @@
 The paged kernels' correctness rests on invariants no unit test states
 directly: every BlockSpec index map must stay inside its operand at
 every grid point (the block table's ``-1`` holes redirect to the null
-page, never out of the pool), scratch buffers must be (8, 128)-tile
-aligned whenever the plan promises tile alignment, ``plan_exec`` must
+page, never out of the pool), every block of a compiled launch must
+satisfy Mosaic's shape rule (last two dims divisible by (8, 128) or
+equal to the operand's — interpret mode never checks it, so a kernel
+can pass every CPU test and still be refused by the chip's compiler),
+scratch buffers must be (8, 128)-tile aligned whenever the plan
+promises tile alignment, ``plan_exec`` must
 resolve the full (interpret, pad) matrix to its documented modes, and
 the masking contract (null pages, ``pos = -1`` holes, ``cache_limit``,
 sliding window, MLA) must stay pinned by parity tests.
@@ -154,6 +158,18 @@ def _eval_index_map(index_map, grid: tuple, prefetch: list):
     return np.stack([np.asarray(c) for c in cols], axis=1)
 
 
+def _mosaic_block_ok(block: tuple, shape: tuple) -> bool:
+    """Mosaic's BlockSpec rule: each of the last two block dims is a
+    multiple of its tile dim (sublanes, lanes) or the operand's full
+    dim.  A squeezed (``None``) dim counts as size 1."""
+    tiles = (_SUBLANES, _LANES)[-len(block):] if block else ()
+    for bs, dim, tile in zip(block[-2:], shape[-2:], tiles):
+        bs = 1 if bs is None else bs
+        if bs % tile and bs != dim:
+            return False
+    return True
+
+
 def check_launch(launch: Launch, *, require_tile: bool, path: str,
                  line: int, where: str) -> list[Finding]:
     """Bounds-check every index map and (optionally) scratch tiling."""
@@ -173,6 +189,12 @@ def check_launch(launch: Launch, *, require_tile: bool, path: str,
                 f"{where}: spec #{spec_i} block rank {len(block)} != "
                 f"operand rank {len(shape)} ({block} vs {shape})"))
             continue
+        if not launch.interpret and not _mosaic_block_ok(block, shape):
+            findings.append(Finding(
+                "kernel-block-shape", path, line,
+                f"{where}: spec #{spec_i} block {block} over operand "
+                f"{shape}: the last two block dims must be divisible by "
+                f"({_SUBLANES}, {_LANES}) or equal the operand's"))
         idx = _eval_index_map(spec.index_map, launch.grid, prefetch)
         for d, bs in enumerate(block):
             if bs is None:
@@ -222,8 +244,8 @@ def _decode_args(*, aligned: bool):
     table[0, 2] = 0
     args = (
         jnp.zeros((B, n, H, Dk), jnp.float32),
-        jnp.zeros((P, n, Hkv, Dk), jnp.float32),
-        jnp.zeros((P, n, Hkv, Dv), jnp.float32),
+        jnp.zeros((P, Hkv, n, Dk), jnp.float32),
+        jnp.zeros((P, Hkv, n, Dv), jnp.float32),
         jnp.zeros((P, n), jnp.int32),
         jnp.asarray(table),
         jnp.zeros((B, n, Hkv, Dk), jnp.float32),
@@ -239,8 +261,6 @@ def _prefill_args(*, aligned: bool):
     if aligned:
         B, bsz, Ts, H, Hkv, Dk, Dv, P, Kp = 2, 8, 2, 4, 2, 128, 128, 6, 2
     else:
-        # Kp + Ts chosen so the compact scratch row count stays a
-        # sublane multiple under tile padding (Lk = (Kp+Ts)*bsz = 16)
         B, bsz, Ts, H, Hkv, Dk, Dv, P, Kp = 2, 4, 2, 4, 2, 40, 40, 5, 2
     T = Ts * bsz
     table = np.full((B, Kp), -1, np.int32)
@@ -248,8 +268,8 @@ def _prefill_args(*, aligned: bool):
     table[1, :] = [0, 1]
     args = (
         jnp.zeros((B, T, H, Dk), jnp.float32),
-        jnp.zeros((P, bsz, Hkv, Dk), jnp.float32),
-        jnp.zeros((P, bsz, Hkv, Dv), jnp.float32),
+        jnp.zeros((P, Hkv, bsz, Dk), jnp.float32),
+        jnp.zeros((P, Hkv, bsz, Dv), jnp.float32),
         jnp.zeros((P, bsz), jnp.int32),
         jnp.asarray(table),
         jnp.zeros((B, T, Hkv, Dk), jnp.float32),
@@ -313,14 +333,14 @@ def _check_paged_kernel(make_args, label: str) -> list[Finding]:
                 "kernel-plan-matrix", path, line,
                 f"{where}: kernel failed abstract evaluation: "
                 f"{type(e).__name__}: {e}"))
-    # the documented fallback: padding disabled + compiled + sub-tile
+    # padding disabled + compiled + sub-tile: compiled unpadded, never
+    # a silent fallback to interpret mode
     plan = pa.plan_exec(4, 40, 40, interpret=False, pad=False)
-    if plan.mode != "interpret" or plan.padded:
+    if plan.mode != "compiled" or plan.padded:
         findings.append(Finding(
             "kernel-plan-matrix", path, 1,
-            "plan_exec(subtile, interpret=False, pad=False) must fall "
-            f"back to interpret mode, got ({plan.mode}, "
-            f"padded={plan.padded})"))
+            "plan_exec(subtile, interpret=False, pad=False) must compile "
+            f"unpadded, got ({plan.mode}, padded={plan.padded})"))
     return findings
 
 

@@ -125,6 +125,14 @@ class KernelScratchTileRule(Rule):
 
 
 @register
+class KernelBlockShapeRule(Rule):
+    id = "kernel-block-shape"
+    doc = ("in a compiled launch, the last two dims of every BlockSpec "
+           "block must be divisible by (8, 128) or equal to the "
+           "operand's own (the rule Mosaic enforces before lowering)")
+
+
+@register
 class KernelPlanMatrixRule(Rule):
     id = "kernel-plan-matrix"
     doc = ("plan_exec must resolve every (interpret, pad) combination to "

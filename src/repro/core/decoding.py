@@ -183,13 +183,13 @@ def prefill_suffix(model, params, suffix_tokens, start_block: jax.Array,
     over it is row- and length-invariant, so the committed suffix KV is
     *byte-identical* to the same blocks of a full ``prefill`` — the
     property the scheduler's prefix-cache on/off token-parity guarantee
-    rests on.  This holds on both prefill KV layouts (``kv_kernel="ref"``
-    gathers the prefix pages into a dense-width copy; ``"pallas"``
-    streams them in place via ``paged_prefill_attention``, which replays
-    the reference chunk walk over a compact scratch copy of the same key
-    layout) and when the cache dtype equals the activation dtype (fp32
-    default); lower-precision caches would round the prefix context
-    where the full pass attends pre-rounding.
+    rests on.  This holds for ``kv_kernel="ref"`` (the prefix pages are
+    gathered into a dense-width copy) when the cache dtype equals the
+    activation dtype (fp32 default); lower-precision caches would round
+    the prefix context where the full pass attends pre-rounding.  With
+    ``"pallas"`` (``paged_prefill_attention`` streams the pages in
+    place, summing its online softmax page by page) the suffix KV
+    agrees to f32 rounding, not bitwise.
     """
     cfg = model.cfg
     B, Ls = suffix_tokens.shape

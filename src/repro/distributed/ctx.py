@@ -1,8 +1,10 @@
 """Activation sharding hints.
 
 ``shard_hint(x, *spec)`` applies a with_sharding_constraint when a mesh
-context is active (the dry-run / production path) and is a no-op on the
-single-device CPU test path.  Axis names that don't exist on the current
+is set with ``jax.set_mesh`` (the dry-run / production path) and is a
+no-op on the single-device path.  A constraint the mesh cannot satisfy
+raises: a hint that silently did nothing would hide the replication it
+exists to prevent.  Axis names that don't exist on the current
 mesh are dropped, so model code can say ("batch", None, None) once and
 have it mean (('pod','data'), ...) on the multi-pod mesh and ('data', ...)
 on the single-pod mesh.
@@ -17,28 +19,16 @@ the intended strategy — all-gather the (small) weight shards instead.
 from __future__ import annotations
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 BATCH = "batch"  # symbolic: expands to the mesh's data-parallel axes
 
 
 def _current_mesh():
-    # `with mesh:` (the dry-run / launcher idiom) sets the legacy thread
-    # resource, not the new abstract-mesh context; check both.  The
-    # abstract-mesh getter only exists on newer jax releases.
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        m = get_abstract()
-        if m is not None and not m.empty:
-            return m
-    try:
-        from jax._src.mesh import thread_resources
-        pm = thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:
-        pass
-    return None
+    """The mesh set by ``jax.set_mesh`` (the launchers' idiom), or None
+    on the single-device path."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def shard_hint(x, *spec):
@@ -57,7 +47,4 @@ def shard_hint(x, *spec):
             axes = ax if isinstance(ax, tuple) else (ax,)
             kept = tuple(a for a in axes if a in names)
             out.append(kept if kept else None)
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*out))
-    except (ValueError, TypeError):
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*out))

@@ -15,6 +15,15 @@
 #       (8, 128) tile so they stay compiled-eligible on TPU.  plan_exec
 #       reports the chosen execution mode.  Validated against the
 #       gathered fallback in models.attention (tests/test_paged_attn.py).
-# Both auto-run interpret=True off-TPU so CPU CI exercises the real
-# kernel paths.  ops.py dispatches the masked-pass implementations and
-# reports the training execution mode via train_exec_plan.
+# Both run compiled on TPU and interpret=True elsewhere, so CPU CI
+# exercises the real kernel bodies; default_interpret below is that one
+# backend-driven choice.  ops.py dispatches the masked-pass
+# implementations and reports the training execution mode via
+# train_exec_plan.
+
+import jax
+
+
+def default_interpret() -> bool:
+    """Run compiled on TPU, interpreted everywhere else (CPU CI)."""
+    return jax.default_backend() != "tpu"

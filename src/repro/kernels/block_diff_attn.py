@@ -21,8 +21,9 @@ initializes to zero.  Per row the kv tiles stay in ascending order, so
 the online-softmax accumulation order — and hence the forward results —
 are bitwise identical to the dense-grid kernel.
 
-Kernels (one ``pallas_call`` each, all gated by the same ``tile_map``
-and the same ``_tile_visibility`` predicate):
+Kernels (one ``pallas_call`` each, all walking worklists compacted from
+the same ``tile_map`` and evaluating the same ``_tile_visibility``
+predicate):
 
 ``_kernel``      forward: online-softmax flash attention over the
                  q-major visited-tile list, accumulating (acc, m, l)
@@ -49,7 +50,7 @@ inference callers pay nothing.  Gradients for the integer operands
 (meta, tile_map) are symbolic zeros (float0).
 
 Memory plan (per grid step): VMEM q/k/v/do tiles, meta tiles
-(TQ|TK, 4) int32, SMEM visited-tile table (5, n_candidates) int32, f32
+(TQ|TK, 4) int32, SMEM visited-tile table (6, n_candidates) int32, f32
 scratch accumulators plus (TQ, 128)-lane running statistics / residual
 tiles.  Validated under ``interpret=True`` on CPU against
 ``ref.mha_reference`` (forward, bitwise vs the seed kernel) and against
@@ -79,29 +80,28 @@ COPY, BLOCK, STEP, POS = 0, 1, 2, 3
 INVALID_COPY = 2  # matches no predicate clause -> never visible
 
 # _compact_tiles table row indices
-TM_B, TM_QI, TM_KI, TM_START, TM_END = 0, 1, 2, 3, 4
-
-
-def default_interpret() -> bool:
-    """Run compiled on TPU, interpreted everywhere else (CPU CI)."""
-    return jax.default_backend() != "tpu"
+TM_B, TM_QI, TM_KI, TM_START, TM_END, TM_NEED = 0, 1, 2, 3, 4, 5
 
 
 def _compact_tiles(tile_map: jax.Array, *, kv_major: bool = False
                    ) -> tuple[jax.Array, jax.Array]:
     """Sort the visited tiles of ``tile_map`` into a dense worklist.
 
-    Returns ``(tmeta, nv)``: ``tmeta`` is a ``(5, n_candidates)`` int32
-    table with rows ``[b, q_tile, kv_tile, row_start, row_end]``, sorted
+    Returns ``(tmeta, nv)``: ``tmeta`` is a ``(6, n_candidates)`` int32
+    table with rows ``[b, q_tile, kv_tile, row_start, row_end, needed]``,
+    sorted
     by (b, major row, minor column) — q-major for the forward/dQ grids,
     kv-major for dKV — and ``nv`` is the (traced) number of live
     entries, which becomes the dynamic grid bound.  Entries past ``nv``
     are never executed.
 
     Every major row with *no* visible tile contributes one dummy entry
-    pointing at its column-0 tile (provably invisible, so the kernel's
-    ``tile_map > 0`` gate skips its compute) — the row's output block is
-    still initialized and written, keeping empty rows exactly zero.
+    pointing at its column-0 tile with ``needed = 0``, so the kernel's
+    ``needed`` gate skips its compute — the row's output block is still
+    initialized and written, keeping empty rows exactly zero.  The flag
+    rides in this scalar-prefetched table rather than as a ``tile_map``
+    operand: a (1, 1, 1) VMEM block of the tile map breaks Mosaic's
+    (8, 128) block rule.
     Within a row, minor columns stay ascending: the flash accumulation
     order is identical to a dense grid's, so results are bitwise equal.
     """
@@ -125,6 +125,8 @@ def _compact_tiles(tile_map: jax.Array, *, kv_major: bool = False
     keys = jnp.concatenate([key_real, key_dummy])
     cand_row = jnp.concatenate([row_id, rid])
     cand_col = jnp.concatenate([col_id, jnp.zeros_like(rid)])
+    cand_need = jnp.concatenate([jnp.ones_like(row_id),
+                                 jnp.zeros_like(rid)])
     order = jnp.argsort(keys)
     skey = keys[order]
     live = skey < big
@@ -137,7 +139,9 @@ def _compact_tiles(tile_map: jax.Array, *, kv_major: bool = False
     b_of = jnp.where(live, srow // R, 0)
     major = jnp.where(live, srow % R, 0)
     qi_of, ki_of = (scol, major) if kv_major else (major, scol)
-    tmeta = jnp.stack([b_of, qi_of, ki_of, start, end]).astype(jnp.int32)
+    need = jnp.where(live, cand_need[order], 0)
+    tmeta = jnp.stack([b_of, qi_of, ki_of, start, end, need]
+                      ).astype(jnp.int32)
     return tmeta, jnp.sum(live.astype(jnp.int32))
 
 
@@ -166,7 +170,10 @@ def _tile_visibility(qm, km, window: int | None, strict: bool):
     else:
         ctx = k_is_a & ((kb < qb) | ((kb == qb) & (ks < qs)))
         own = k_is_b & (kb == qb) & (ks >= qs)
-    vis = jnp.where(qc == 0, vis_a_query, ctx | own)
+    # boolean select spelled as and/or: Mosaic cannot lower a select
+    # between i1 vectors (it widens to i8 and fails to truncate back)
+    q_is_a = qc == 0
+    vis = (q_is_a & vis_a_query) | (~q_is_a & (ctx | own))
     # invalid (padding) queries match nothing, mirroring the oracle's
     # q.valid gate — so their rows are empty and their grads exactly 0
     vis = vis & (qc != INVALID_COPY)
@@ -175,7 +182,7 @@ def _tile_visibility(qm, km, window: int | None, strict: bool):
     return vis
 
 
-def _kernel(tmeta_ref, tile_map_ref, qm_ref, km_ref, q_ref, k_ref, v_ref,
+def _kernel(tmeta_ref, qm_ref, km_ref, q_ref, k_ref, v_ref,
             o_ref, *rest, scale: float, softcap: float | None,
             window: int | None, strict: bool, emit_lse: bool = False):
     if emit_lse:
@@ -190,9 +197,7 @@ def _kernel(tmeta_ref, tile_map_ref, qm_ref, km_ref, q_ref, k_ref, v_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    needed = tile_map_ref[0, 0, 0] > 0
-
-    @pl.when(needed)
+    @pl.when(tmeta_ref[TM_NEED, t] > 0)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # (TQ, D)
         k = k_ref[0, 0].astype(jnp.float32)          # (TK, D)
@@ -260,7 +265,7 @@ def _tile_dscore(p, s_capped, do, v, delta, *, softcap):
     return ds
 
 
-def _dq_kernel(tmeta_ref, tile_map_ref, qm_ref, km_ref, q_ref, k_ref,
+def _dq_kernel(tmeta_ref, qm_ref, km_ref, q_ref, k_ref,
                v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc_ref, *,
                scale: float, softcap: float | None, window: int | None,
                strict: bool):
@@ -270,7 +275,7 @@ def _dq_kernel(tmeta_ref, tile_map_ref, qm_ref, km_ref, q_ref, k_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(tile_map_ref[0, 0, 0] > 0)
+    @pl.when(tmeta_ref[TM_NEED, t] > 0)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
@@ -290,7 +295,7 @@ def _dq_kernel(tmeta_ref, tile_map_ref, qm_ref, km_ref, q_ref, k_ref,
         dq_ref[0, 0] = acc_ref[...]
 
 
-def _dkv_kernel(tmeta_ref, tile_map_ref, qm_ref, km_ref, q_ref, k_ref,
+def _dkv_kernel(tmeta_ref, qm_ref, km_ref, q_ref, k_ref,
                 v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
                 dk_acc, dv_acc, *, scale: float, softcap: float | None,
                 window: int | None, strict: bool):
@@ -301,7 +306,7 @@ def _dkv_kernel(tmeta_ref, tile_map_ref, qm_ref, km_ref, q_ref, k_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(tile_map_ref[0, 0, 0] > 0)
+    @pl.when(tmeta_ref[TM_NEED, t] > 0)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)
         k = k_ref[0, 0].astype(jnp.float32)
@@ -345,14 +350,10 @@ def _specs(H, group, tq, tk, D, Dv, *, out_axis: str):
     def km_map(h, t, tm):
         return (tm[TM_B, t], tm[TM_KI, t], 0)
 
-    def tm_map(h, t, tm):
-        return (tm[TM_B, t], tm[TM_QI, t], tm[TM_KI, t])
-
     def kout(h, t, tm):
         return (tm[TM_B, t], h, tm[TM_KI, t], 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, 1), tm_map),
         pl.BlockSpec((1, tq, 4), qm_map),
         pl.BlockSpec((1, tk, 4), km_map),
         pl.BlockSpec((1, 1, tq, D), qmap),
@@ -405,7 +406,7 @@ def _forward(q, k, v, q_meta, k_meta, tile_map, *, scale, softcap, window,
         ),
         out_shape=out_shape,
         interpret=interpret,
-    )(tmeta, tm, q_meta, k_meta, qh, kh, vh)
+    )(tmeta, q_meta, k_meta, qh, kh, vh)
 
     if emit_lse:
         o, lse = res
@@ -454,7 +455,7 @@ def _backward(q, k, v, q_meta, k_meta, tile_map, o, lse, do, *, scale,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Lq, D), jnp.float32),
         interpret=interpret,
-    )(tmeta_q, tm, q_meta, k_meta, qh, kh, vh, doh, lse, delta)
+    )(tmeta_q, q_meta, k_meta, qh, kh, vh, doh, lse, delta)
 
     # dKV walks the kv-major list: each kv row's visited q tiles are
     # consecutive, accumulating dk/dv in scratch
@@ -479,7 +480,7 @@ def _backward(q, k, v, q_meta, k_meta, tile_map, o, lse, do, *, scale,
             jax.ShapeDtypeStruct((B, H, Lk, Dv), jnp.float32),
         ],
         interpret=interpret,
-    )(tmeta_k, tm, q_meta, k_meta, qh, kh, vh, doh, lse, delta)
+    )(tmeta_k, q_meta, k_meta, qh, kh, vh, doh, lse, delta)
 
     dq = dq.transpose(0, 2, 1, 3).astype(q.dtype)
     # per-q-head dk/dv -> sum the group axis back onto the kv heads

@@ -39,8 +39,8 @@ import jax.numpy as jnp
 
 from repro.core.masks import SeqMeta, visibility
 from . import ref as _ref
-from .block_diff_attn import (INVALID_COPY, block_diff_attention,
-                              default_interpret)
+from . import default_interpret
+from .block_diff_attn import INVALID_COPY, block_diff_attention
 
 NEG_INF = _ref.NEG_INF
 
@@ -56,12 +56,19 @@ class TrainExecPlan:
 
 
 def train_exec_plan(impl: str) -> TrainExecPlan:
-    """Resolve ``impl`` to its execution mode on the current backend."""
-    if impl in ("pallas", "pallas_interpret"):
-        if impl == "pallas_interpret" or default_interpret():
+    """Resolve ``impl`` to its execution mode on the current backend.
+
+    ``pallas`` compiles on TPU and interprets elsewhere;
+    ``pallas_interpret`` is the explicit request for interpret mode.
+    ``attention`` launches the kernels from this plan, so the reported
+    mode is the mode that runs."""
+    if impl == "pallas_interpret":
+        return TrainExecPlan(impl, "interpret", "interpret requested")
+    if impl == "pallas":
+        if default_interpret():
             return TrainExecPlan(impl, "interpret",
-                                 "pallas kernels on non-TPU backend "
-                                 "(interpret mode)")
+                                 f"backend={jax.default_backend()} "
+                                 "(compiled Mosaic path needs a TPU)")
         return TrainExecPlan(impl, "compiled", "pallas kernels on TPU")
     return TrainExecPlan(impl, "xla", f"pure-jnp {impl} path (XLA)")
 
@@ -151,8 +158,8 @@ def layout_tile_stats(meta: SeqMeta, *, tq: int = 128, tk: int = 128,
     ``attention`` dispatcher."""
     pm = pack_meta(meta)
     L = pm.shape[1]
-    tq = _pick_chunk(L, tq)
-    tk = _pick_chunk(L, tk)
+    tq = _pick_tile(L, tq)
+    tk = _pick_tile(L, tk)
     return tile_map_stats(build_tile_map(pm, pm, tq, tk, window=window))
 
 
@@ -220,6 +227,16 @@ def _pick_chunk(length: int, target: int) -> int:
     while length % c:
         c -= 1
     return c
+
+
+def _pick_tile(length: int, target: int) -> int:
+    """Largest divisor of ``length`` <= target that is a multiple of 8,
+    else ``length`` itself: Mosaic takes a block's second-minor dim only
+    as a multiple of the 8-row sublane tile or as the whole dim."""
+    for c in range(min(target, length) // 8 * 8, 0, -8):
+        if length % c == 0:
+            return c
+    return length
 
 
 def chunked_masked_attention(q, k, v, q_meta: SeqMeta, k_meta: SeqMeta, *,
@@ -362,7 +379,7 @@ def attention(q, k, v, q_meta: SeqMeta, k_meta: SeqMeta, *,
     ``dup_len``/``block_size`` enable the structured fast path when the
     layout is the DiRL duplicated layout (copy A = first ``dup_len``
     positions).  ``pallas`` clamps ``tq``/``tk`` to divisors of Lq/Lk
-    (framework layouts are block-aligned, so this always succeeds) and
+    that are multiples of 8 (or to the whole length, where none is) and
     is differentiable — the custom-VJP backward kernels skip the same
     empty tiles as the forward — so it is valid under ``jax.grad`` and
     ``jax.checkpoint`` in the trainers.
@@ -380,16 +397,17 @@ def attention(q, k, v, q_meta: SeqMeta, k_meta: SeqMeta, *,
             q, k, v, q_meta, dup_len, block_size,
             scale=scale, softcap=softcap, window=window, strict=strict)
     if impl in ("pallas", "pallas_interpret"):
-        # clamp tiles to divisors so model-layer defaults (128) work at
-        # any block-aligned length; interpret off-TPU (CI runs the real
-        # kernel bodies on CPU, mirroring paged_attn.plan_exec)
-        tq = _pick_chunk(q.shape[1], tq)
-        tk = _pick_chunk(k.shape[1], tk)
+        # clamp tiles to sublane-aligned divisors so model-layer
+        # defaults (128) compile at any length; the exec mode is
+        # train_exec_plan's (interpret off-TPU so CI runs the real
+        # kernel bodies on CPU)
+        tq = _pick_tile(q.shape[1], tq)
+        tk = _pick_tile(k.shape[1], tk)
         qm = pack_meta(q_meta)
         km = pack_meta(k_meta)
         tile_map = build_tile_map(qm, km, tq, tk, window=window)
         return block_diff_attention(
             q, k, v, qm, km, tile_map, scale=scale, softcap=softcap,
             window=window, strict=strict, tq=tq, tk=tk,
-            interpret=(impl == "pallas_interpret") or default_interpret())
+            interpret=train_exec_plan(impl).mode == "interpret")
     raise ValueError(f"unknown attention impl: {impl}")

@@ -99,7 +99,7 @@ def run_combo(arch: str, shape: str, mesh_kind: str, *,
            "n_chips": n_chips, "ok": False}
     t0 = time.time()
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             jfn, args, si = _build(arch, shape, mesh)
             lowered = jfn.lower(*args)
             t_lower = time.time() - t0

@@ -10,13 +10,13 @@ per-request ``SamplingParams`` on ONE slot pool, exercising the
 request-granular decode path (no engine rebuild, no retrace per
 config).  A single value behaves as before.
 
-``--cache paged --kernel pallas`` serves the pool through the in-place
-page-aware kernels (``kernels.paged_attn`` — decode and suffix
-prefill); the stats line then reports the per-tick and admission-time
-transient KV copies (0 in place vs the gathered fallback's dense-width
-bytes) plus the kernels' execution mode — ``compiled`` or
-``interpret``, and why — so TPU users can see when a sub-tile page
-shape or a non-TPU backend silently put them on the slow path.
+By default (``--cache paged --kernel pallas``) the pool is served
+through the in-place page-aware kernels (``kernels.paged_attn`` —
+decode and suffix prefill); the stats line then reports the per-tick
+and admission-time transient KV copies (0 in place vs the gathered
+fallback's dense-width bytes) plus the kernels' execution mode —
+``compiled`` on TPU, ``interpret`` elsewhere — and why.  The persistent
+compile cache is placed by ``launch.compile_cache``.
 """
 
 from __future__ import annotations
@@ -50,15 +50,16 @@ def main():
     ap.add_argument("--slots", type=int, default=4,
                     help="decode-slot pool size (continuous batching)")
     ap.add_argument("--cache", choices=["dense", "paged"],
-                    default="dense",
-                    help="KV layout: per-slot regions | shared page pool")
+                    default="paged",
+                    help="KV layout: shared page pool | per-slot regions")
     ap.add_argument("--pages", type=int, default=None,
                     help="paged: pool size (default = dense-equivalent)")
-    ap.add_argument("--kernel", choices=["ref", "pallas"], default="ref",
-                    help="paged decode KV layout: gather pages into a "
-                         "dense-width copy per step (ref) or read the "
-                         "page pool in place (pallas; interpret-mode "
-                         "off-TPU)")
+    ap.add_argument("--kernel", choices=["ref", "pallas"],
+                    default="pallas",
+                    help="paged KV layout: read the page pool in place "
+                         "(pallas; compiled on TPU, interpret mode "
+                         "elsewhere) or gather pages into a dense-width "
+                         "copy per step (ref)")
     ap.add_argument("--prefix-cache", default=None,
                     action=argparse.BooleanOptionalAction,
                     help="paged: share committed prompt pages across "
@@ -80,6 +81,9 @@ def main():
     args = ap.parse_args()
 
     import jax
+
+    from repro.launch import compile_cache
+    compile_cache.configure()
 
     from repro import configs
     from repro.checkpoint.io import load_pytree

@@ -36,11 +36,11 @@ def main():
                     default="host")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced same-family config")
-    ap.add_argument("--attn-impl", default="structured",
+    ap.add_argument("--attn-impl", default="pallas",
                     choices=["ref", "structured", "chunked", "pallas"],
                     help="training attention backend; pallas runs the "
-                         "differentiable tile-sparse kernels (interpret "
-                         "mode off-TPU)")
+                         "differentiable tile-sparse kernels (compiled "
+                         "on TPU, interpret mode elsewhere)")
     ap.add_argument("--dry-run", action="store_true",
                     help="lower+compile only (see repro.launch.dryrun for "
                          "the full sweep)")
@@ -71,6 +71,9 @@ def main():
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.launch import compile_cache
+    compile_cache.configure()
+
     from repro import configs
     from repro.checkpoint.io import save_pytree
     from repro.data.pipeline import MathTaskDataset
@@ -95,7 +98,7 @@ def main():
     opt_cfg = adamw.AdamWConfig(lr=args.lr, clip_norm=1.0)
     step_fn = make_train_step(model, opt_cfg)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params_shape = jax.eval_shape(model.init,
                                       jax.ShapeDtypeStruct((2,), jnp.uint32))
         pspecs = shd.sanitize_specs(
@@ -151,7 +154,7 @@ def main():
             server = ModelServer(jax.tree.map(jnp.copy, params))
             engine = RolloutEngine(model, server, GenerationConfig(
                 max_len=args.seq_len, s_max=4, mode="dynamic", tau=0.7,
-                temperature=1.0, cache="paged",
+                temperature=1.0, cache="paged", kernel="pallas",
                 n_slots=max(args.rl_prompts * args.group_size // 2, 2)),
                 tokenizer=tok)
             rl_cfg = DiPOConfig(group_size=args.group_size,

@@ -47,8 +47,10 @@ cached keys reach the attention math:
 
 All layouts implement the same masking contract — null page 0,
 ``pos = -1`` empty slots, per-row ``cache_limit``, sliding window, and
-the MLA latent-MQA form — and produce byte-identical decode tokens and
-suffix-prefill activations (tests/test_paged_attn.py).
+the MLA latent-MQA form.  The gathered layouts are byte-identical to
+the dense ones; the in-place kernels agree with them to f32 rounding
+(online softmax sums in another order) and, at the tested seeds, give
+the same decode tokens (tests/test_paged_attn.py).
 ``transient_kv_bytes`` quantifies the per-decode-step copy each layout
 pays and ``prefill_transient_kv_bytes`` the admission-time gather
 width (both 0 for the in-place kernels); ``kernel_exec_plan`` reports
@@ -95,9 +97,15 @@ class PagedAttnCache(NamedTuple):
     re-enters its prompt region, and evicted slots dump their idempotent
     re-commits into the null page — so sharing needs refcounts but no
     copy-on-write.
+
+    K/V pages are head-major, (P, Hkv, bsz, D): one kv head's page is a
+    contiguous (bsz, D) tile, the block the Pallas kernels DMA per grid
+    step (Mosaic requires a block's last two dims to be (8, 128)
+    multiples or the array's own, which a (bsz, 1, D) slice of a
+    token-major page is not).
     """
-    k: jax.Array    # (P, bsz, Hkv, Dk) rotated
-    v: jax.Array    # (P, bsz, Hkv, Dv)
+    k: jax.Array    # (P, Hkv, bsz, Dk) rotated
+    v: jax.Array    # (P, Hkv, bsz, Dv)
     pos: jax.Array  # (P, bsz) int32, -1 = empty
 
 
@@ -112,9 +120,15 @@ def make_attn_cache(batch: int, seq: int, n_kv: int, dk: int, dv: int,
 def make_paged_attn_cache(n_pages: int, block_size: int, n_kv: int,
                           dk: int, dv: int, dtype) -> PagedAttnCache:
     return PagedAttnCache(
-        k=jnp.zeros((n_pages, block_size, n_kv, dk), dtype),
-        v=jnp.zeros((n_pages, block_size, n_kv, dv), dtype),
+        k=jnp.zeros((n_pages, n_kv, block_size, dk), dtype),
+        v=jnp.zeros((n_pages, n_kv, block_size, dv), dtype),
         pos=jnp.full((n_pages, block_size), -1, jnp.int32))
+
+
+def _to_pages(a: jax.Array) -> jax.Array:
+    """Token-major blocks (..., bsz, Hkv, D) <-> head-major pages
+    (..., Hkv, bsz, D) (the swap is its own inverse)."""
+    return jnp.swapaxes(a, -3, -2)
 
 
 def paged_gather(cache: PagedAttnCache, table: jax.Array):
@@ -134,11 +148,12 @@ def paged_gather(cache: PagedAttnCache, table: jax.Array):
     """
     B, K = table.shape
     idx = jnp.maximum(table, 0)                    # -1 -> null page 0
-    k, v, pos = cache.k[idx], cache.v[idx], cache.pos[idx]
-    pos = jnp.where(table[:, :, None] >= 0, pos, -1)
-    bsz = cache.k.shape[1]
-    return (k.reshape(B, K * bsz, *cache.k.shape[2:]),
-            v.reshape(B, K * bsz, *cache.v.shape[2:]),
+    # head-major pages back to token-major rows: (B, K, bsz, Hkv, D)
+    k, v = _to_pages(cache.k[idx]), _to_pages(cache.v[idx])
+    pos = jnp.where(table[:, :, None] >= 0, cache.pos[idx], -1)
+    Hkv, bsz = cache.k.shape[1:3]
+    return (k.reshape(B, K * bsz, Hkv, k.shape[-1]),
+            v.reshape(B, K * bsz, Hkv, v.shape[-1]),
             pos.reshape(B, K * bsz))
 
 
@@ -152,14 +167,14 @@ def paged_cache_write(cache: PagedAttnCache, k: jax.Array, v: jax.Array,
     re-committing its frozen block) are dumped into the null page with
     ``pos`` = -1, so they can never corrupt a live sequence's page.
     """
-    bsz = cache.k.shape[1]
+    bsz = cache.k.shape[2]
     rows = jnp.arange(k.shape[0], dtype=jnp.int32)
     page = table[rows, positions[:, 0] // bsz]     # (B,)
     safe = jnp.maximum(page, 0)
     pos_w = jnp.where(page[:, None] >= 0, positions.astype(jnp.int32), -1)
     return PagedAttnCache(
-        k=cache.k.at[safe].set(k.astype(cache.k.dtype)),
-        v=cache.v.at[safe].set(v.astype(cache.v.dtype)),
+        k=cache.k.at[safe].set(_to_pages(k).astype(cache.k.dtype)),
+        v=cache.v.at[safe].set(_to_pages(v).astype(cache.v.dtype)),
         pos=cache.pos.at[safe].set(pos_w))
 
 
@@ -170,7 +185,7 @@ def write_prompt_pages(cache: PagedAttnCache, row: AttnCache,
     ``row`` leaves are (1, L, ...) with L a block multiple (a ring-free
     prefill); ``pages`` (Kp,) holds the page ids for the first Kp blocks.
     """
-    bsz = cache.k.shape[1]
+    bsz = cache.k.shape[2]
     Kp = pages.shape[0]
 
     def blocks(a):
@@ -178,16 +193,18 @@ def write_prompt_pages(cache: PagedAttnCache, row: AttnCache,
         return a.reshape(L // bsz, bsz, *a.shape[2:])[:Kp]
 
     return PagedAttnCache(
-        k=cache.k.at[pages].set(blocks(row.k).astype(cache.k.dtype)),
-        v=cache.v.at[pages].set(blocks(row.v).astype(cache.v.dtype)),
+        k=cache.k.at[pages].set(
+            _to_pages(blocks(row.k)).astype(cache.k.dtype)),
+        v=cache.v.at[pages].set(
+            _to_pages(blocks(row.v)).astype(cache.v.dtype)),
         pos=cache.pos.at[pages].set(blocks(row.pos)))
 
 
 def write_prompt_pages_grouped(cache: PagedAttnCache, row: AttnCache,
                                pages: jax.Array) -> PagedAttnCache:
     """``write_prompt_pages`` for G-stacked group caches: pool leaves are
-    (G, P, bsz, ...) and the prefill row's are (G, 1, L, ...)."""
-    bsz = cache.k.shape[2]
+    (G, P, ...) and the prefill row's are (G, 1, L, ...)."""
+    bsz = cache.k.shape[3]
     Kp = pages.shape[0]
 
     def blocks(a):
@@ -195,8 +212,10 @@ def write_prompt_pages_grouped(cache: PagedAttnCache, row: AttnCache,
         return a.reshape(G, L // bsz, bsz, *a.shape[3:])[:, :Kp]
 
     return PagedAttnCache(
-        k=cache.k.at[:, pages].set(blocks(row.k).astype(cache.k.dtype)),
-        v=cache.v.at[:, pages].set(blocks(row.v).astype(cache.v.dtype)),
+        k=cache.k.at[:, pages].set(
+            _to_pages(blocks(row.k)).astype(cache.k.dtype)),
+        v=cache.v.at[:, pages].set(
+            _to_pages(blocks(row.v)).astype(cache.v.dtype)),
         pos=cache.pos.at[:, pages].set(blocks(row.pos)))
 
 
@@ -211,7 +230,7 @@ def write_suffix_pages(cache: PagedAttnCache, k: jax.Array, v: jax.Array,
     this writes several blocks per row in one shot and has no null-page
     escape: suffix pages are always freshly allocated.
     """
-    bsz = cache.k.shape[1]
+    bsz = cache.k.shape[2]
     B, T = positions.shape
     Ks = T // bsz
 
@@ -221,8 +240,8 @@ def write_suffix_pages(cache: PagedAttnCache, k: jax.Array, v: jax.Array,
 
     idx = pages.reshape(-1)
     return PagedAttnCache(
-        k=cache.k.at[idx].set(blocks(k).astype(cache.k.dtype)),
-        v=cache.v.at[idx].set(blocks(v).astype(cache.v.dtype)),
+        k=cache.k.at[idx].set(_to_pages(blocks(k)).astype(cache.k.dtype)),
+        v=cache.v.at[idx].set(_to_pages(blocks(v)).astype(cache.v.dtype)),
         pos=cache.pos.at[idx].set(blocks(positions.astype(jnp.int32))))
 
 
@@ -341,13 +360,15 @@ def gqa_plain_paged(p, x, meta: SeqMeta, cache: PagedAttnCache,
     ``x``/``meta`` cover only the suffix rows (absolute positions);
     attention keys are the shared-prefix pages behind ``context_table``
     followed by the suffix's own K/V — the same key layout and masking
-    as the full plain pass, so the computed suffix KV (committed into
-    ``write_pages``) is bitwise identical to what a full prefill would
-    have produced (when the cache dtype equals the activation dtype;
-    see core.decoding.prefill_suffix).  ``kernel`` picks how the prefix
-    pages are read: ``"ref"`` gathers them into a dense-width copy,
-    ``"pallas"`` streams them in place (``paged_prefill_attention``),
-    eliminating the admission-time transient.
+    as the full plain pass, so with ``kernel="ref"`` the computed
+    suffix KV (committed into ``write_pages``) is bitwise identical to
+    what a full prefill would have produced (when the cache dtype
+    equals the activation dtype; see core.decoding.prefill_suffix), and
+    with ``kernel="pallas"`` equal to f32 rounding.  ``kernel`` picks
+    how the prefix pages are read: ``"ref"`` gathers them into a
+    dense-width copy, ``"pallas"`` streams them in place
+    (``paged_prefill_attention``), eliminating the admission-time
+    transient.
     """
     B, T, _ = x.shape
     q, k, v = gqa_qkv(p, x, meta.pos, cfg)
@@ -414,8 +435,9 @@ class KVLayout:
                        context_table, block_size, impl, scale, softcap,
                        window):
         """Plain-mode pass of suffix queries over (shared-prefix pages
-        ++ suffix self keys); must be bitwise equal to the full-prefill
-        chunked kernel over the same key layout (the
+        ++ suffix self keys); must match the full-prefill chunked
+        kernel over the same key layout — bitwise for the gathered
+        layout, to f32 rounding for the in-place kernel (the
         ``serving.prefix_cache`` invariant)."""
         raise NotImplementedError
 
@@ -495,13 +517,13 @@ class _GatheredPagedKV(KVLayout):
 
     @staticmethod
     def transient_bytes(cache, n_rows: int, n_blocks: int) -> int:
-        bsz = cache.k.shape[-3]
+        bsz = cache.k.shape[-2]
         return n_rows * n_blocks * bsz * _kv_token_bytes(cache)
 
     @staticmethod
     def prefill_transient_bytes(cache, n_rows: int,
                                 n_ctx_blocks: int) -> int:
-        bsz = cache.k.shape[-3]
+        bsz = cache.k.shape[-2]
         return n_rows * n_ctx_blocks * bsz * _kv_token_bytes(cache)
 
 
@@ -547,7 +569,9 @@ _KV_LAYOUTS = {
 
 def _kv_token_bytes(cache) -> int:
     """Per-token bytes of one (k, v, pos) cache entry."""
-    hkv, dk = cache.k.shape[-2], cache.k.shape[-1]
+    hkv = cache.k.shape[-3] if isinstance(cache, PagedAttnCache) \
+        else cache.k.shape[-2]
+    dk = cache.k.shape[-1]
     dv = cache.v.shape[-1]
     return hkv * (dk * cache.k.dtype.itemsize
                   + dv * cache.v.dtype.itemsize) + 4
@@ -595,7 +619,7 @@ def kernel_exec_plan(cache, kernel: str = "ref"):
     if kernel != "pallas" or not isinstance(cache, PagedAttnCache):
         return None
     from repro.kernels.paged_attn import plan_exec
-    bsz = cache.k.shape[-3]
+    bsz = cache.k.shape[-2]
     return plan_exec(bsz, cache.k.shape[-1], cache.v.shape[-1])
 
 

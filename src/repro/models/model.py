@@ -530,8 +530,9 @@ class BlockDiffLM:
         resolve_kv_layout): ``"ref"`` gathers the hit-prefix pages into
         a dense-width copy once per admission, ``"pallas"`` streams
         them in place (``kernels.paged_attn.paged_prefill_attention``),
-        so admission pays zero transient KV bytes.  Both produce
-        bitwise-identical suffix KV.
+        so admission pays zero transient KV bytes.  ``"ref"`` gives
+        suffix KV bitwise identical to a full prefill, ``"pallas"``
+        equal to f32 rounding.
         """
         ctx = LayerCtx(mode="plain", meta=meta,
                        context_table=context_table,

@@ -18,33 +18,19 @@ time is the question, this module is the answer:
     ``logdir`` and open in TensorBoard's profile plugin or Perfetto.
     Wired to ``launch.serve --profile-dir``.  ``logdir=None`` is a
     no-op, so call sites can pass the CLI flag straight through.
-
-Both degrade to no-ops when ``jax.profiler`` is unavailable (the
-``available()`` probe), keeping the obs package importable on stripped
-builds.
 """
 
 from __future__ import annotations
 
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
-try:                                        # pragma: no cover - import guard
-    from jax import profiler as _jprof
-except Exception:                           # pragma: no cover
-    _jprof = None
+from jax import profiler as _jprof
 
-__all__ = ["annotate", "available", "capture"]
-
-
-def available() -> bool:
-    """True when ``jax.profiler`` annotation/trace APIs are present."""
-    return _jprof is not None and hasattr(_jprof, "TraceAnnotation")
+__all__ = ["annotate", "capture"]
 
 
 def annotate(name: str):
-    """Named profiler scope (no-op context if jax.profiler is absent)."""
-    if not available():
-        return nullcontext()
+    """Named profiler scope."""
     return _jprof.TraceAnnotation(name)
 
 
@@ -52,10 +38,10 @@ def annotate(name: str):
 def capture(logdir: str | None):
     """Run the body under an XLA profiler trace written to ``logdir``.
 
-    ``None`` (flag unset) or a missing profiler degrade to a plain
-    pass-through so callers need no conditional.
+    ``None`` (flag unset) is a plain pass-through so callers need no
+    conditional; yields whether a trace is being captured.
     """
-    if logdir is None or not available():
+    if logdir is None:
         yield False
         return
     _jprof.start_trace(str(logdir))

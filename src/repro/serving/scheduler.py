@@ -89,7 +89,9 @@ would otherwise prefill and store the identical prompt G times.
     blocks map the *existing* pages into the new slot's table
     (refcount++) and only the suffix is prefilled
     (``core.decoding.prefill_suffix`` — byte-identical to the same
-    blocks of a full prefill; a full hit skips the model entirely).
+    blocks of a full prefill on the gathered layout, equal to f32
+    rounding with the in-place kernel; a full hit skips the model
+    entirely).
     Freshly prefilled prompt blocks are registered into the index.
   * eviction — a slot releases its prompt-page references; a page
     returns to the free list only when *exclusive* (generated blocks,

@@ -123,7 +123,9 @@ class OfflineWeightStore:
     def _gc(self, keep: int) -> None:
         """Delete superseded checkpoints — an online RL run writes one
         per step, which is unbounded disk growth if never reaped."""
-        for p in glob.glob(os.path.join(self.root, "ckpt_*.msgpack")):
+        # escaped: a root holding glob metacharacters ("[") must still match
+        for p in glob.glob(os.path.join(glob.escape(self.root),
+                                        "ckpt_*.msgpack")):
             if p == self._path(keep):
                 continue
             try:

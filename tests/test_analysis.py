@@ -94,7 +94,8 @@ def test_rule_registry_complete():
     assert set(RULES) == {
         "trace-branch", "trace-host-pull", "hot-sync", "obs-in-trace",
         "post-donation-read", "kernel-oob-index", "kernel-scratch-tile",
-        "kernel-plan-matrix", "kernel-parity-coverage"}
+        "kernel-block-shape", "kernel-plan-matrix",
+        "kernel-parity-coverage"}
     for rule in RULES.values():
         assert rule.doc
 
@@ -348,6 +349,31 @@ def test_misaligned_scratch_fires_only_when_tiled():
     assert _rules(findings) == {"kernel-scratch-tile"}
     assert check_launch(bad, require_tile=False, path="fix.py",
                         line=1, where="prefill") == []
+
+
+def test_misaligned_block_shape_fires_when_compiled():
+    """The Mosaic BlockSpec rule, checked on CPU: a (1, 1, 1) block of
+    a (2, 4, 4) tile map — the spec the TPU compiler refused in the
+    training kernel — is flagged in a compiled launch; interpret mode
+    does not enforce the rule, and aligned or full-extent blocks pass."""
+    def tm_map(i):
+        return (0, i, 0)
+
+    bad = _launch(in_specs=[pl.BlockSpec((1, 1, 1), tm_map)],
+                  operands=[np.zeros((2, 4, 4), np.int32)], interpret=False)
+    findings = check_launch(bad, require_tile=False, path="fix.py",
+                            line=1, where="fwd")
+    assert _rules(findings) == {"kernel-block-shape"}
+    assert check_launch(dataclasses.replace(bad, interpret=True),
+                        require_tile=False, path="fix.py", line=1,
+                        where="fwd") == []
+    ok = _launch(in_specs=[pl.BlockSpec((1, 8, 4), lambda i: (0, 0, 0)),
+                           pl.BlockSpec((1, 8, 128), lambda i: (0, i, 0))],
+                 operands=[np.zeros((2, 8, 4), np.int32),
+                           np.zeros((2, 24, 256), np.float32)],
+                 interpret=False)
+    assert check_launch(ok, require_tile=False, path="fix.py", line=1,
+                        where="fwd") == []
 
 
 def test_capture_launches_records_and_short_circuits():

@@ -3,8 +3,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-pytest.importorskip("hypothesis")  # optional dep: skip file when absent
 from hypothesis import given, settings, strategies as st
 
 from repro.core import masks as M

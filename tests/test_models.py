@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-pytest.importorskip("hypothesis")  # optional dep: skip file when absent
 from hypothesis import given, settings, strategies as st
 
 from repro.models import attention as attn

@@ -14,17 +14,8 @@ from repro.models.model import BlockDiffLM
 from repro.models.modules import tree_paths
 
 
-def _abstract_mesh(sizes, names):
-    # jax >= 0.5 takes (sizes, names); 0.4.x takes a shape tuple of
-    # (name, size) pairs.
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _param_shapes(arch):

@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-pytest.importorskip("hypothesis")  # optional dep: skip file when absent
 from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint.io import load_pytree, save_pytree
@@ -135,6 +134,34 @@ def test_offline_store_roundtrips_through_fs(tmp_path):
                                   np.asarray(params["w"]))
     store.update_weights({"w": jnp.full((8, 8), 4.0)})
     assert float(store.params["w"][0, 0]) == 4.0
-    # files actually exist on disk (the Fig 5a IO cost is real)
-    assert len(os.listdir(tmp_path)) >= 2
+    # the live version's file actually exists on disk (the Fig 5a IO
+    # cost is real); superseded ones are reaped (test_offline_store_gc)
+    assert os.listdir(tmp_path) == [f"ckpt_{store.version}.msgpack"]
+    assert os.path.getsize(tmp_path / f"ckpt_{store.version}.msgpack") > 0
     assert store.load_seconds > 0
+
+
+# ------------------------------ compile cache ------------------------------
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """An outside JAX_COMPILATION_CACHE_DIR wins and nothing is set in
+    code; without it the cache goes to the fixed, gitignored
+    ``.jax_cache`` of the checkout — never a per-run path."""
+    from pathlib import Path
+    from repro.launch import compile_cache
+
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.configure() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == old
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first = compile_cache.configure()
+        assert first == compile_cache.configure()
+        assert jax.config.jax_compilation_cache_dir == first
+        root = Path(__file__).resolve().parents[1]
+        assert Path(first) == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
