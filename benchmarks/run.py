@@ -9,9 +9,9 @@ shapes and *validates the emitted JSON artifact* against the shared
 schema (``common.validate_bench_json``).  Any suite that raises makes
 the run exit nonzero, with or without ``--smoke``.
 
-The roofline/dry-run artifacts (deliverables e/g) are produced separately
-by ``python -m repro.launch.dryrun --all`` and summarised by
-``python -m repro.launch.rooflines``; this harness reports their status.
+The dry-run artifacts (deliverables e/g) are produced separately by
+``python -m repro.launch.dryrun --all``; this harness reports their
+status.
 """
 
 from __future__ import annotations

@@ -84,8 +84,8 @@ _HOST_PULL_METHODS = {"item", "tolist"}
 _SYNC_ATTRS = {"block_until_ready", "device_get"}
 
 # obs modules whose calls must never be jit-reachable (profile/export
-# are not listed: annotate() is trace-legal and exporters are cold
-# paths no jit site can reach)
+# are not listed: capture() and the exporters are cold paths no jit
+# site can reach)
 _OBS_MODULES = {"repro.obs", "repro.obs.metrics", "repro.obs.trace"}
 # Tracer's recording API: a `<anything>.tracer.<one of these>()` chain
 # is an obs call even when the receiver cannot be resolved statically

@@ -406,6 +406,7 @@ def _forward(q, k, v, q_meta, k_meta, tile_map, *, scale, softcap, window,
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name="block_diff_fwd",
     )(tmeta, q_meta, k_meta, qh, kh, vh)
 
     if emit_lse:
@@ -455,6 +456,7 @@ def _backward(q, k, v, q_meta, k_meta, tile_map, o, lse, do, *, scale,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Lq, D), jnp.float32),
         interpret=interpret,
+        name="block_diff_dq",
     )(tmeta_q, q_meta, k_meta, qh, kh, vh, doh, lse, delta)
 
     # dKV walks the kv-major list: each kv row's visited q tiles are
@@ -480,6 +482,7 @@ def _backward(q, k, v, q_meta, k_meta, tile_map, o, lse, do, *, scale,
             jax.ShapeDtypeStruct((B, H, Lk, Dv), jnp.float32),
         ],
         interpret=interpret,
+        name="block_diff_dkv",
     )(tmeta_k, q_meta, k_meta, qh, kh, vh, doh, lse, delta)
 
     dq = dq.transpose(0, 2, 1, 3).astype(q.dtype)

@@ -244,8 +244,9 @@ def _rows(x: jax.Array, nq: int, group: int) -> jax.Array:
 
 def _launch(q, k_pages, v_pages, pos_pages, table, k_self, v_self,
             positions, pool_limit, *, bsz: int, qc: int, scale, softcap,
-            window, plan: KernelPlan):
-    """Shared wrapper: lay the operands out for ``_kernel`` and launch.
+            window, plan: KernelPlan, name: str):
+    """Shared wrapper: lay the operands out for ``_kernel`` and launch
+    it as ``name`` (the entry point's, so a trace tells the two apart).
 
     q (B, T, H, Dk); pool leaves (P, Hkv, bsz, D) / pos (P, bsz); table
     (B, n_pool); self K/V (B, T, Hkv, D) block-aligned; positions (B, T);
@@ -337,6 +338,7 @@ def _launch(q, k_pages, v_pages, pos_pages, table, k_self, v_self,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, nq, R, dvp), q.dtype),
         interpret=plan.interpret,
+        name=name,
     )(table, qh, kp, vp, pp, ksh, vsh, spos, qpos, plim, slim)
     out = out.reshape(B, Hkv, nq, group, qc, dvp).transpose(0, 2, 4, 1, 3, 5)
     return out.reshape(B, T, H, dvp)[..., :Dv]
@@ -382,7 +384,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         cache_limit.astype(jnp.int32)[:, None], (B, n))
     return _launch(q, k_pages, v_pages, pos_pages, table, k_self, v_self,
                    positions, pool_limit, bsz=bsz, qc=n, scale=scale,
-                   softcap=softcap, window=window, plan=plan)
+                   softcap=softcap, window=window, plan=plan,
+                   name="paged_decode")
 
 
 def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
@@ -424,4 +427,5 @@ def paged_prefill_attention(q: jax.Array, k_pages: jax.Array,
     return _launch(q, k_pages, v_pages, pos_pages, context_table, k_self,
                    v_self, positions, block_end, bsz=bsz,
                    qc=_pick_chunk(T, _Q_CHUNK), scale=scale,
-                   softcap=softcap, window=window, plan=plan)
+                   softcap=softcap, window=window, plan=plan,
+                   name="paged_prefill")

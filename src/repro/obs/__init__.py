@@ -14,22 +14,22 @@ sit on per-tick serving paths without touching the device:
    namespace convention.
 
 2. **Tracing** (``obs.trace``): a span tracer with a bounded ring
-   buffer.  The scheduler records per-request lifecycle spans (submit →
-   queued → admit → decode → harvest, labeled with prefix-hit counts,
-   slot id, kernel mode, finish reason) and per-tick sub-spans (admit /
-   advance / harvest).  Timestamps are host wall-clock taken *around*
-   jit dispatch — spans never call ``block_until_ready``, so the
-   ``hot-sync`` dirlint contract holds by construction and a span's
-   duration is dispatch + host bookkeeping, not device time.  Honest
-   device timing stays behind ``GenerationConfig.sync_each_tick`` or a
-   real profiler capture (below).
+   buffer.  Spans are named by layer (``scheduler.tick``,
+   ``scheduler.advance``, ``sft.step``...) and carry the counts at
+   their boundary and their enclosing span.  The scheduler records
+   per-request lifecycle spans (submit → queued → admit → decode →
+   harvest, labeled with prefix-hit counts, slot id, kernel mode,
+   finish reason) and per-tick sub-spans.  Timestamps are host
+   wall-clock taken *around* jit dispatch — spans never call
+   ``block_until_ready``, so the ``hot-sync`` dirlint contract holds by
+   construction and a span's duration is dispatch + host bookkeeping,
+   not device time.  Spans record when the tracer is enabled or when a
+   ``jax.profiler`` session is capturing; under a capture they are
+   also profiler annotations on the device trace's clock, and
+   ``obs.trace.captured()`` returns them.
 
-3. **Profiler hooks** (``obs.profile``): thin wrappers over
-   ``jax.profiler`` — ``annotate(name)`` puts named
-   ``TraceAnnotation`` scopes around ``advance_block`` / suffix
-   prefill / trainer steps (visible in XLA profiler traces), and
-   ``capture(dir)`` brackets a region with a real
-   ``start_trace``/``stop_trace`` profiler session
+3. **Profiler capture** (``obs.profile``): ``capture(dir)`` brackets a
+   region with a real ``start_trace``/``stop_trace`` profiler session
    (``launch.serve --profile-dir``).
 
 Exporters (``obs.export``) turn both substrates into artifacts: Chrome

@@ -58,7 +58,6 @@ from repro.core import decoding
 from repro.core.masks import packed_layout
 from repro.core.trajectory import trajectory_logprobs
 from repro.kernels.ops import layout_tile_stats
-from repro.obs import profile
 from repro.obs.metrics import MetricsRegistry
 from repro.optim import adamw
 from repro.rl.pipeline.producer import RolloutProducer
@@ -165,11 +164,11 @@ class AsyncDiPOTrainer:
         raw: list[dict] = []
         P = producer.submit_next()        # first batch defines P
         for i in range(steps):
-            with self.tracer.span("fill", cat="consumer",
+            with self.tracer.span("dipo.fill", cat="consumer",
                                   track="consumer", update=i) as sp_fill:
                 version = self._fill(producer, queue, P, steps)
 
-            with self.tracer.span("train", cat="consumer",
+            with self.tracer.span("dipo.train", cat="consumer",
                                   track="consumer", update=i) as sp_train:
                 groups = queue.pop_batch(P, version)
                 # FIFO pop order is completion order; restore prompt
@@ -183,14 +182,16 @@ class AsyncDiPOTrainer:
                 roll = decoding.rollout_to_batch(
                     gen, jnp.asarray(rewards), jnp.asarray(gid), bsz)
                 if self._stats_layout:
-                    _, meta, _, _ = packed_layout(
-                        roll.tokens, roll.steps, roll.valid,
-                        block_size=bsz,
-                        mask_token=self.model.cfg.resolved_mask_token,
-                        s_max=self.engine.gen_cfg.s_max)
-                    stats = layout_tile_stats(meta)
-                    for f, g in self._tile_gauges.items():
-                        g.set(stats[f])
+                    with self.tracer.span("dipo.tile_stats",
+                                          cat="consumer", track="consumer"):
+                        _, meta, _, _ = packed_layout(
+                            roll.tokens, roll.steps, roll.valid,
+                            block_size=bsz,
+                            mask_token=self.model.cfg.resolved_mask_token,
+                            s_max=self.engine.gen_cfg.s_max)
+                        stats = layout_tile_stats(meta)
+                        for f, g in self._tile_gauges.items():
+                            g.set(stats[f])
                 old_logp = fresh = None
                 if self.staleness_k > 0:
                     # one executable for any fresh/sealed mix: sealed
@@ -214,7 +215,8 @@ class AsyncDiPOTrainer:
                 if self.ref_params is not None:
                     ref_logp = jax.lax.stop_gradient(
                         self._ref_logp(self.ref_params, roll))
-                with profile.annotate("dipo_step"):
+                with self.tracer.span("dipo.dispatch", cat="consumer",
+                                      track="consumer"):
                     self.params, self.opt_state, metrics = self._step(
                         self.params, self.opt_state, roll, old_logp,
                         fresh, ref_logp, P)
@@ -225,7 +227,7 @@ class AsyncDiPOTrainer:
             # donation window: the step above donated the buffers the
             # server still references — land the push before anything
             # can tick the pool or read server params
-            with self.tracer.span("update", cat="consumer",
+            with self.tracer.span("dipo.update", cat="consumer",
                                   track="consumer", update=i) as sp_upd:
                 new_version = self.engine.store.update_weights(
                     self.params, sync=False)
@@ -250,7 +252,9 @@ class AsyncDiPOTrainer:
         # update (float() blocks on each device value)
         history = []
         for r in raw:
-            m = {k: float(v) for k, v in r["metrics"].items()}
+            with self.tracer.span("dipo.metric_pull", cat="consumer",
+                                  track="consumer"):
+                m = {k: float(v) for k, v in r["metrics"].items()}
             m.update(r["timing"])
             m["reward_mean"] = float(np.mean(r["rewards"]))
             m["acc"] = float(np.mean(r["rewards"] >= 1.0))

@@ -135,7 +135,7 @@ class RolloutProducer:
                 for p in range(P) for _ in range(G)]
         plist, _ = self.engine._resolve_sampling(P * G, sampling, blocks)
         sched = self.engine.scheduler
-        with self.tracer.span("submit_batch", cat="producer",
+        with self.tracer.span("producer.submit_batch", cat="producer",
                               track="producer", batch=self.next_batch,
                               prompts=P):
             for p in range(P):
@@ -194,7 +194,7 @@ class RolloutProducer:
         answers = np.full((G,), slot["answer"], np.int64)
         versions = [int(v) for c in comps
                     for v in (c.param_version, *c.block_versions)]
-        with self.tracer.span("finalize_group", cat="producer",
+        with self.tracer.span("producer.finalize_group", cat="producer",
                               track="producer", prompt_id=pid,
                               batch=slot["batch"]):
             rewards = math_rewards(self.engine.tok, gen, answers, bsz)
@@ -227,7 +227,7 @@ class RolloutProducer:
         else:
             version, params = getattr(store, "version", 0), store.params
         bsz = self.engine.model.cfg.block_size
-        with self.tracer.span("seal_backlog", cat="producer",
+        with self.tracer.span("producer.seal_backlog", cat="producer",
                               track="producer", groups=len(todo)):
             for g in todo:
                 if g.version != version:

@@ -22,7 +22,6 @@ from repro.core.dipo import dipo_loss
 from repro.core.masks import packed_layout
 from repro.core.trajectory import trajectory_logprobs
 from repro.kernels.ops import layout_tile_stats
-from repro.obs import profile
 from repro.obs.metrics import MetricsRegistry
 from repro.rl.rewards import math_rewards
 from repro.optim import adamw
@@ -149,10 +148,15 @@ class DiPOTrainer:
             scheme=rl_cfg.logprob_scheme))
 
     def train_step(self, prompt_batch, rng) -> dict:
+        with self.tracer.span("dipo.step", cat="trainer", track="trainer"):
+            return self._train_step(prompt_batch, rng)
+
+    def _train_step(self, prompt_batch, rng) -> dict:
         cfg = self.rl_cfg
         bsz = self.model.cfg.block_size
         P = prompt_batch.prompt_tokens.shape[0]
         G = cfg.group_size
+        span = self.tracer.span
 
         # ---- rollout (G per prompt) ----------------------------------
         # the group entry keeps each group's members adjacent, so a
@@ -162,8 +166,7 @@ class DiPOTrainer:
         # obs spans replace the old perf_counter pairs: same intervals,
         # but they also land on the shared tracer (track "trainer") and
         # aggregate into the dirl_trainer phase histogram.
-        with self.tracer.span("rollout", cat="trainer",
-                              track="trainer") as sp_roll:
+        with span("dipo.rollout", cat="trainer", track="trainer") as sp_roll:
             answers = np.repeat(prompt_batch.answers, G, axis=0)
             rng, kr = jax.random.split(rng)
             sampling = None
@@ -178,31 +181,29 @@ class DiPOTrainer:
                 kr, G, sampling=sampling)
 
         # ---- rewards ---------------------------------------------------
-        with self.tracer.span("reward", cat="trainer",
-                              track="trainer") as sp_rew:
+        with span("dipo.reward", cat="trainer", track="trainer") as sp_rew:
             rewards = math_rewards(self.engine.tok, gen, answers, bsz)
             group = np.repeat(np.arange(P, dtype=np.int32), G)
             roll = decoding.rollout_to_batch(
                 gen, jnp.asarray(rewards), jnp.asarray(group), bsz)
 
         # ---- logits + policy update -----------------------------------
-        with self.tracer.span("train", cat="trainer",
-                              track="trainer") as sp_train:
+        with span("dipo.train", cat="trainer", track="trainer") as sp_train:
             ref_logp = None
             if self.ref_params is not None:
                 ref_logp = jax.lax.stop_gradient(
                     self._ref_logp(self.ref_params, roll))
-            with profile.annotate("dipo_step"):
+            with span("dipo.dispatch", cat="trainer", track="trainer"):
                 self.params, self.opt_state, metrics = self._step(
                     self.params, self.opt_state, roll, None, None,
                     ref_logp, P)
-            # deliberate: t_train must measure the real step, and metrics
-            # are pulled to host right below anyway
-            jax.block_until_ready(metrics["loss"])  # dirlint: ok(hot-sync)
+            with span("dipo.sync", cat="trainer", track="trainer"):
+                # deliberate: t_train must measure the real step, and
+                # metrics are pulled to host right below anyway
+                jax.block_until_ready(metrics["loss"])  # dirlint: ok(hot-sync)
 
         # ---- in-place server update ------------------------------------
-        with self.tracer.span("update", cat="trainer",
-                              track="trainer") as sp_upd:
+        with span("dipo.update", cat="trainer", track="trainer") as sp_upd:
             self.engine.store.update_weights(self.params)
             # offline stores pay the reload on the *next* rollout;
             # in-place stores are done here.
@@ -217,19 +218,21 @@ class DiPOTrainer:
         if self._stats_layout:
             # host-side rebuild of the packed mask metadata (cheap: meta
             # only, no forward) -> per-step sparsity gauges
-            _, meta, _, _ = packed_layout(
-                roll.tokens, roll.steps, roll.valid, block_size=bsz,
-                mask_token=self.model.cfg.resolved_mask_token,
-                s_max=self.engine.gen_cfg.s_max)
-            stats = layout_tile_stats(meta)
-            for f, g in self._tile_gauges.items():
-                g.set(stats[f])
+            with span("dipo.tile_stats", cat="trainer", track="trainer"):
+                _, meta, _, _ = packed_layout(
+                    roll.tokens, roll.steps, roll.valid, block_size=bsz,
+                    mask_token=self.model.cfg.resolved_mask_token,
+                    s_max=self.engine.gen_cfg.s_max)
+                stats = layout_tile_stats(meta)
+                for f, g in self._tile_gauges.items():
+                    g.set(stats[f])
         if self.engine.last_call.get("batching") == "continuous":
             timing["rollout_util"] = self.engine.last_call["utilization"]
             timing["prefix_hit_rate"] = \
                 self.engine.last_call["prefix_hit_rate"]
         self.timings.append(timing)
-        out = {k: float(v) for k, v in metrics.items()}
+        with span("dipo.metric_pull", cat="trainer", track="trainer"):
+            out = {k: float(v) for k, v in metrics.items()}
         out.update(timing)
         out["step_traces"] = self._step.n_traces
         out["reward_mean"] = float(np.mean(rewards))

@@ -107,6 +107,7 @@ class Request:
     prompt_blocks: int           # true prompt length in blocks
     rng: "object"                # (2,) per-request rng key
     params: SamplingParams = SamplingParams()
+    submitted: float = 0.0       # time.perf_counter() at submit()
 
 
 @dataclasses.dataclass
@@ -134,7 +135,7 @@ class RequestOutput:
     prompt_blocks: int
     gen_blocks: int
     gen_tokens: int              # generated tokens to first EOS incl.
-    denoise_steps: int           # denoise steps actually executed
+    denoise_steps: int           # denoise steps that revealed tokens
     admitted_tick: int           # scheduler tick the request entered
     completed_tick: int          # scheduler tick it finished
     params: SamplingParams = SamplingParams()

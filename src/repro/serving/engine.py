@@ -25,13 +25,15 @@ Contracts kept:
     streams; see core.decoding), so rl/trainer.py, launch/serve.py and
     the fig6/fig7 benchmarks run unchanged.
   * ``generate_texts`` — texts trimmed at the first EOS.
-  * ``EngineStats`` — throughput counters, now *honest*:
-    ``total_steps`` counts denoise steps actually executed (dynamic
-    early-exit included), not ``blocks * s_max``; ``total_tokens``
-    counts generated tokens up to the first EOS inclusive (not the
-    block-padded tail); continuous runs also record slot utilization
-    (active slot-ticks / paid slot-ticks) and admit→finish latency
-    (``latency_p50`` / ``latency_p95``, in scheduler ticks).
+  * ``EngineStats`` — throughput counters: ``total_steps`` counts
+    the denoise steps that revealed tokens (each block's step-map max
+    + 1), not the forwards executed — the pool runs ``s_max + 1`` per
+    block whatever it needs (``SchedulerStats.denoise_forwards``);
+    ``total_tokens`` counts generated tokens up to the first EOS
+    inclusive (not the block-padded tail); continuous runs also record
+    slot utilization (active slot-ticks / paid slot-ticks) and
+    admit→finish latency (``latency_p50`` / ``latency_p95``, in
+    scheduler ticks).
 
 The continuous path's KV layout is selectable: ``cache="dense"`` (each
 slot owns a ``max_len`` cache region) or ``cache="paged"`` (slots share
@@ -101,7 +103,7 @@ class EngineStats:
     """
     rollouts: int = 0
     total_tokens: int = 0
-    total_steps: int = 0          # denoise steps actually executed
+    total_steps: int = 0          # denoise steps that revealed tokens
     wall_seconds: float = 0.0
     slot_ticks: int = 0           # continuous: paid slot-steps
     active_slot_ticks: int = 0    # continuous: useful slot-steps
@@ -156,6 +158,8 @@ class EngineStats:
 
     @property
     def tokens_per_step(self) -> float:
+        """Generated tokens per revealing denoise step (not per
+        forward executed)."""
         return self.total_tokens / max(self.total_steps, 1)
 
     @property
@@ -273,7 +277,7 @@ class RolloutEngine:
         """
         # one obs span defines wall_seconds for the whole call on both
         # paths (a disabled tracer still times; see EngineStats docs)
-        with self.tracer.span("generate_ids", cat="engine",
+        with self.tracer.span("engine.generate_ids", cat="engine",
                               track="engine",
                               batching=self.gen_cfg.batching) as sp:
             self.stats.param_version = getattr(self.store, "version", 0)
@@ -457,7 +461,7 @@ class RolloutEngine:
                 # engine-side wall time: pool tick + (stream) completion
                 # packaging; consumer wait between yields excluded —
                 # the same definition generate_ids uses
-                with self.tracer.span("stream_tick", cat="engine",
+                with self.tracer.span("engine.stream_tick", cat="engine",
                                       track="engine") as sp:
                     self._pending.extend(
                         sched.step(p, param_version=version))
@@ -492,7 +496,7 @@ class RolloutEngine:
         With ``params=None`` the live store weights are re-read every
         tick, so in-place server updates take effect mid-stream."""
         for comp in self.stream_completions(params):
-            with self.tracer.span("package", cat="engine",
+            with self.tracer.span("engine.package", cat="engine",
                                   track="engine", uid=comp.uid) as psp:
                 out = self._to_output(comp)
             self.stats.wall_seconds += psp.dur

@@ -149,6 +149,7 @@ same-width admission, and optimistic admission + preemption.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Iterator
 
@@ -161,7 +162,6 @@ from repro.core import decoding
 from repro.core.masks import plain_layout
 from repro.kernels.ops import layout_tile_stats
 from repro.models import attention
-from repro.obs import profile
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Tracer
 from repro.serving.api import GenerationConfig, Request, SamplingParams
@@ -181,7 +181,10 @@ class Completion:
     prompt_blocks: int
     gen_blocks: int
     gen_tokens: int              # generated tokens up to first EOS incl.
-    denoise_steps: int           # actual denoise steps executed (dynamic)
+    # denoise steps that revealed tokens (each block's step_map max + 1,
+    # summed): what a loop that stopped once a block was revealed would
+    # run; SchedulerStats.denoise_forwards counts the forwards executed
+    denoise_steps: int
     finish_reason: str           # "eos" | "length" (hit block budget)
     admitted_tick: int
     completed_tick: int
@@ -226,7 +229,10 @@ class SchedulerStats:
     admitted: int = 0
     completed: int = 0
     gen_tokens: int = 0          # tokens served, cut at first EOS incl.
-    denoise_steps: int = 0       # actual denoise steps across requests
+    denoise_steps: int = 0       # revealing denoise steps, all requests
+    # forwards executed: (s_max + 1) per advance (s_max denoise steps
+    # and the commit), whatever the blocks needed
+    denoise_forwards: int = 0
     peak_active: int = 0         # max concurrently live slots
     prefill_blocks: int = 0      # prompt blocks actually prefilled
     # per-tick cache-KV bytes the decode layout copies out of the
@@ -254,6 +260,10 @@ class SchedulerStats:
     prefix_miss_blocks: int = 0  # prompt blocks that paid a prefill
     shared_pages: int = 0        # peak pages referenced by >= 2 slots
     prefix_evictions: int = 0    # refcount-0 index entries LRU-reclaimed
+    # sum over advances of the committed blocks of the live slots going
+    # in (paged only): the context the paged decode kernel needs, of
+    # the n_slots x table-width entries its grid walks per forward
+    ctx_block_ticks: int = 0
     # tile-map visit fraction of the most recent admission's prefill
     # attention (block-causal mask at block granularity) — the sparsity
     # the tile-sparse kernel family skips on the serve side
@@ -262,9 +272,11 @@ class SchedulerStats:
     # monotonic fields -> Counter; level/peak fields -> Gauge
     _COUNTER_FIELDS = ("ticks", "slot_ticks", "active_slot_ticks",
                        "admitted", "completed", "gen_tokens",
-                       "denoise_steps", "prefill_blocks", "deferred",
-                       "page_allocs", "page_frees", "prefix_hit_blocks",
-                       "prefix_miss_blocks", "prefix_evictions")
+                       "denoise_steps", "denoise_forwards",
+                       "prefill_blocks", "deferred", "page_allocs",
+                       "page_frees", "prefix_hit_blocks",
+                       "prefix_miss_blocks", "prefix_evictions",
+                       "ctx_block_ticks")
     _GAUGE_FIELDS = ("peak_active", "transient_kv_bytes",
                      "admit_transient_kv_bytes", "advance_traces",
                      "peak_pages_in_use", "peak_pages_live",
@@ -392,6 +404,13 @@ class SlotScheduler:
         self._slot_admit_abs: list[int] = [0] * n_slots
         self._tick_versions: list[int] = []
         self._next_uid = 0
+        # per-tick counts for the scheduler.tick span
+        self._n_tick_admitted = self._n_tick_evicted = 0
+        self._n_tick_alloc = self._n_tick_wiped = 0
+        # host copy of state.n_denoise as of the last pull (None when
+        # stale): a recorded tick diffs it to get each live row's
+        # revealing steps without an extra transfer in steady state
+        self._nd_host: np.ndarray | None = None
         self._state = self._init_pool()
         # pool-static (cache layout + kernel choice fix it at
         # construction); re-stamped into stats every tick so the common
@@ -696,7 +715,8 @@ class SlotScheduler:
             self._note_prefill_tiles(req)
             self._admit_info = {"path": "cold", "hit_blocks": 0,
                                 "new_pages": pb}
-            with profile.annotate("prefill"):
+            with self.tracer.span("scheduler.prefill", cat="scheduler",
+                                  track="scheduler"):
                 self._state = self._admit_jit(
                     params, self._state, jnp.int32(slot),
                     req.prompt[None], jnp.asarray([pb], jnp.int32),
@@ -750,7 +770,8 @@ class SlotScheduler:
             # cold prompt: the PR-2 path — one B=1 plain prefill,
             # scattered into the fresh pages (then registered above)
             self._admit_info["path"] = "cold"
-            with profile.annotate("prefill"):
+            with self.tracer.span("scheduler.prefill", cat="scheduler",
+                                  track="scheduler"):
                 self._state = self._admit_jit(
                     params, self._state, jnp.int32(slot),
                     req.prompt[None], jnp.asarray([pb], jnp.int32),
@@ -770,7 +791,8 @@ class SlotScheduler:
                 self.stats.admit_transient_kv_bytes,
                 self._admit_transient_kv_bytes(h))
             self._admit_info["path"] = "suffix_prefill"
-            with profile.annotate("prefill_suffix"):
+            with self.tracer.span("scheduler.prefill_suffix",
+                                  cat="scheduler", track="scheduler"):
                 self._state = self._admit_suffix_jit(
                     params, self._state, jnp.int32(slot),
                     req.prompt[None, h * bsz:], jnp.asarray(row),
@@ -844,7 +866,8 @@ class SlotScheduler:
                                    prompt=prompt[:prompt_blocks * bsz],
                                    prompt_blocks=prompt_blocks,
                                    rng=jnp.asarray(rng),
-                                   params=params))
+                                   params=params,
+                                   submitted=time.perf_counter()))
         # lifecycle span 1/2: queued, closed at admission (or at the
         # zero-budget short circuit) with the wait labeled
         self.tracer.begin(("queued", uid), f"req {uid} queued",
@@ -918,6 +941,7 @@ class SlotScheduler:
                 jnp.asarray(blks, jnp.int32)].set(
                     jnp.asarray(pages, jnp.int32))
             self._state = dataclasses.replace(self._state, table=table)
+        self._n_tick_alloc += len(slots)
         self.stats.page_allocs += len(slots)
         self.stats.peak_pages_in_use = max(self.stats.peak_pages_in_use,
                                            self.pages_in_use)
@@ -960,6 +984,7 @@ class SlotScheduler:
         cached keys by design, which become stale the moment the entry
         leaves the index.
         """
+        self._n_tick_wiped += len(pages)
         idx = jnp.asarray(pages, jnp.int32)
 
         def wipe(c, grouped):
@@ -990,90 +1015,129 @@ class SlotScheduler:
         ``update_weights`` without draining the pool.  Returns the
         completions harvested this tick (possibly empty).
 
-        Instrumentation: the tick and its three phases are recorded as
-        tracer spans on the ``scheduler`` track; admitted requests get
+        Instrumentation: the tick is a ``scheduler.tick`` span with
+        ``scheduler.admit`` (one per admission), ``scheduler.alloc_pages``,
+        ``scheduler.advance`` and ``scheduler.harvest`` children on the
+        ``scheduler`` track, each carrying its counts when it records
+        (tracer enabled, or a profiler capture); admitted requests get
         lifecycle spans on per-slot tracks.  All span timestamps are
         host wall-clock around jit *dispatch* — the tracer never syncs
         the device, so instrumentation cannot change tokens, retraces,
         or the ``hot-sync`` contract (tests assert byte-parity and
-        ``n_advance_traces == 1`` with tracing on).
+        ``n_advance_traces == 1`` with tracing on).  A recorded tick
+        copies ``n_denoise`` after ``done`` in its harvest (and at its
+        start where the previous tick was not recorded).
         """
         if isinstance(params, SamplingParams):
             raise TypeError(
                 "step(params=) takes model weights; per-request "
                 "SamplingParams belong on submit(..., params=...)")
-        with self.tracer.span("tick", cat="scheduler", track="scheduler",
-                              tick=self.stats.ticks):
-            return self._tick(params, param_version)
+        tick, active0 = self.stats.ticks, self.stats.active_slot_ticks
+        self._n_tick_admitted = self._n_tick_evicted = 0
+        self._n_tick_alloc = self._n_tick_wiped = 0
+        with self.tracer.span("scheduler.tick", cat="scheduler",
+                              track="scheduler") as sp:
+            out = self._tick(params, param_version, sp.recording)
+            if sp.recording:
+                sp.args.update(
+                    tick=tick,
+                    active=self.stats.active_slot_ticks - active0,
+                    admitted=self._n_tick_admitted,
+                    evicted=self._n_tick_evicted,
+                    alloc_pages=self._n_tick_alloc,
+                    wiped_pages=self._n_tick_wiped)
+        return out
 
-    def _tick(self, params, param_version: int = 0) -> list[Completion]:
+    def _admit(self, params, slot: int, req: Request, budget: int,
+               param_version: int) -> bool:
+        """Admit ``req`` into ``slot``; False when the paged pool
+        defers it (nothing mutated)."""
+        limit = req.prompt_blocks + budget
+        with self.tracer.span("scheduler.admit", cat="scheduler",
+                              track="scheduler") as sp:
+            if self.cache == "paged":
+                if limit > self.n_usable_pages:
+                    raise ValueError(
+                        f"request {req.uid} needs {limit} pages but "
+                        f"the pool only has {self.n_usable_pages}")
+                if not self._admit_paged(params, slot, req, budget):
+                    if sp.recording:
+                        sp.args.update(uid=req.uid, slot=slot,
+                                       path="deferred")
+                    return False
+            else:
+                self.stats.prefill_blocks += req.prompt_blocks
+                self._note_prefill_tiles(req)
+                self._admit_info = {"path": "dense", "hit_blocks": 0}
+                with self.tracer.span("scheduler.prefill",
+                                      cat="scheduler", track="scheduler"):
+                    self._state = self._admit_jit(
+                        params, self._state, jnp.int32(slot),
+                        req.prompt[None],
+                        jnp.asarray([req.prompt_blocks], jnp.int32),
+                        req.rng, jnp.int32(limit),
+                        self._samp_scalars(req.params), None)
+            if sp.recording:
+                info = self._admit_info
+                sp.args.update(uid=req.uid, slot=slot, path=info["path"],
+                               hit_blocks=info["hit_blocks"],
+                               new_pages=info.get("new_pages", 0),
+                               queue_s=time.perf_counter() - req.submitted)
+        self._queue.popleft()
+        self._slot_req[slot] = req
+        self._slot_admit_tick[slot] = self.stats.ticks
+        self._slot_admit_version[slot] = param_version
+        self._slot_admit_abs[slot] = len(self._tick_versions)
+        if self._nd_host is not None:
+            self._nd_host[slot] = 0      # admission resets n_denoise
+        self.stats.admitted += 1
+        self._n_tick_admitted += 1
+        # lifecycle span 2/2: decode, one track per slot — closed at
+        # harvest with the finish labels
+        info = self._admit_info
+        self.tracer.end(("queued", req.uid), slot=slot, **info)
+        self.tracer.begin(
+            ("decode", req.uid), f"req {req.uid}",
+            cat="request", track=f"slot {slot}", uid=req.uid,
+            slot=slot, kernel_mode=self.stats.kernel_mode,
+            prompt_blocks=req.prompt_blocks, budget=budget, **info)
+        return True
+
+    def _tick(self, params, param_version: int,
+              recorded: bool) -> list[Completion]:
         self.stats.transient_kv_bytes = self.transient_kv_bytes
         if not self.stats.kernel_mode and self.kernel_plan:
             self.stats.kernel_mode = self.kernel_plan.mode
+        if not recorded:
+            self._nd_host = None
+        elif self._nd_host is None:
+            # the previous advance has finished (its harvest read
+            # ``done``), so this copy waits for nothing
+            self._nd_host = np.array(self._state.n_denoise)
         # ---- admit queued requests into free slots -------------------
         out: list[Completion] = []
-        with self.tracer.span("admit", cat="scheduler",
-                              track="scheduler") as adm:
-            n_adm = 0
-            for slot in range(self.n_slots):
-                if not self._queue or self._slot_req[slot] is not None:
-                    continue
-                req = self._queue[0]
-                budget = self.n_blocks_total - req.prompt_blocks
-                if req.params.max_new_blocks is not None:
-                    budget = min(budget, req.params.max_new_blocks)
-                if budget <= 0:
-                    # nothing to decode (prompt fills the cache / zero
-                    # block budget) — complete immediately, never touch
-                    # a slot
-                    self._queue.popleft()
-                    out.append(self._empty_completion(req, param_version))
-                    self.tracer.end(("queued", req.uid), outcome="empty")
-                    continue
-                limit = req.prompt_blocks + budget
-                if self.cache == "paged":
-                    if limit > self.n_usable_pages:
-                        raise ValueError(
-                            f"request {req.uid} needs {limit} pages but "
-                            f"the pool only has {self.n_usable_pages}")
-                    if not self._admit_paged(params, slot, req, budget):
-                        # out of pages: defer the FIFO head until
-                        # evictions free some (backpressure, not a crash)
-                        self.stats.deferred += 1
-                        self.tracer.instant("defer", cat="scheduler",
-                                            track="scheduler",
-                                            uid=req.uid,
-                                            queued=len(self._queue))
-                        break
-                else:
-                    self.stats.prefill_blocks += req.prompt_blocks
-                    self._note_prefill_tiles(req)
-                    self._admit_info = {"path": "dense", "hit_blocks": 0}
-                    with profile.annotate("prefill"):
-                        self._state = self._admit_jit(
-                            params, self._state, jnp.int32(slot),
-                            req.prompt[None],
-                            jnp.asarray([req.prompt_blocks], jnp.int32),
-                            req.rng, jnp.int32(limit),
-                            self._samp_scalars(req.params), None)
+        for slot in range(self.n_slots):
+            if not self._queue or self._slot_req[slot] is not None:
+                continue
+            req = self._queue[0]
+            budget = self.n_blocks_total - req.prompt_blocks
+            if req.params.max_new_blocks is not None:
+                budget = min(budget, req.params.max_new_blocks)
+            if budget <= 0:
+                # nothing to decode (prompt fills the cache / zero block
+                # budget) — complete immediately, never touch a slot
                 self._queue.popleft()
-                self._slot_req[slot] = req
-                self._slot_admit_tick[slot] = self.stats.ticks
-                self._slot_admit_version[slot] = param_version
-                self._slot_admit_abs[slot] = len(self._tick_versions)
-                self.stats.admitted += 1
-                n_adm += 1
-                # lifecycle span 2/2: decode, one track per slot —
-                # closed at harvest with the finish labels
-                info = self._admit_info
-                self.tracer.end(("queued", req.uid), slot=slot, **info)
-                self.tracer.begin(
-                    ("decode", req.uid), f"req {req.uid}",
-                    cat="request", track=f"slot {slot}", uid=req.uid,
-                    slot=slot, kernel_mode=self.stats.kernel_mode,
-                    prompt_blocks=req.prompt_blocks, budget=budget,
-                    **info)
-            adm.args["admitted"] = n_adm
+                out.append(self._empty_completion(req, param_version))
+                self.tracer.end(("queued", req.uid), outcome="empty")
+                continue
+            if not self._admit(params, slot, req, budget, param_version):
+                # out of pages: defer the FIFO head until evictions free
+                # some (backpressure, not a crash)
+                self.stats.deferred += 1
+                self.tracer.instant("scheduler.defer", cat="scheduler",
+                                    track="scheduler", uid=req.uid,
+                                    queued=len(self._queue))
+                break
 
         self.stats.peak_active = max(self.stats.peak_active,
                                      self.n_active)
@@ -1081,15 +1145,28 @@ class SlotScheduler:
             return out
 
         # ---- advance the whole pool by one block ---------------------
-        # span brackets page allocation + jit dispatch; advance_block's
-        # result is left unsynced, so dur is dispatch time unless
-        # sync_each_tick (engine) or a profiler capture asks for more
-        with self.tracer.span("advance", cat="scheduler",
-                              track="scheduler", n_active=self.n_active):
-            if self.cache == "paged":
+        # spans bracket page allocation and jit dispatch; advance_block's
+        # result is left unsynced, so their dur is dispatch time
+        if self.cache == "paged":
+            with self.tracer.span("scheduler.alloc_pages", cat="scheduler",
+                                  track="scheduler") as sp:
                 self._alloc_cursor_pages()
-            with profile.annotate("advance_block"):
-                self._state = self._advance(params, self._state)
+                if sp.recording:
+                    sp.args["pages"] = self._n_tick_alloc
+            ctx_blocks = sum(self._slot_blk[s] for s, r in
+                             enumerate(self._slot_req) if r is not None)
+            self.stats.ctx_block_ticks += ctx_blocks
+        forwards = self.gen_cfg.s_max + 1
+        with self.tracer.span("scheduler.advance", cat="scheduler",
+                              track="scheduler") as sp:
+            self._state = self._advance(params, self._state)
+            if sp.recording:
+                sp.args["forwards"] = forwards
+                if self.cache == "paged":
+                    sp.args.update(
+                        ctx_blocks=ctx_blocks,
+                        walked_blocks=self.n_slots * self.n_blocks_total)
+        self.stats.denoise_forwards += forwards
         # every live slot committed one block under these weights
         self._tick_versions.append(param_version)
         self.stats.advance_traces = self._advance.n_traces
@@ -1105,76 +1182,97 @@ class SlotScheduler:
                                                self._slot_limit[slot])
 
         # ---- evict finished slots ------------------------------------
-        with self.tracer.span("harvest", cat="scheduler",
+        with self.tracer.span("scheduler.harvest", cat="scheduler",
                               track="scheduler") as hv:
-            done = np.asarray(self._state.done)
-            evicted: list[int] = []
+            with self.tracer.span("scheduler.harvest.sync",
+                                  cat="scheduler", track="scheduler"):
+                done = np.asarray(self._state.done)
+            if recorded:
+                # 1 commit forward + the most denoise steps any live
+                # row's block used: what an early-exit loop would run.
+                # n_denoise came out of the program ``done`` just
+                # waited for, so this copy waits for nothing
+                nd = np.array(self._state.n_denoise)
+                live = [s for s, r in enumerate(self._slot_req)
+                        if r is not None]
+                forwards_needed = 1 + int(
+                    (nd[live] - self._nd_host[live]).max())
+                self._nd_host = nd
+            fin = [s for s, r in enumerate(self._slot_req)
+                   if r is not None and done[s]]
             freed_pages: list[int] = []
-            for slot in range(self.n_slots):
-                req = self._slot_req[slot]
-                if req is None or not done[slot]:
-                    continue
-                tokens = np.asarray(self._state.tokens[slot])
-                steps = np.asarray(self._state.steps[slot])
-                gen_blocks = int(self._state.blk[slot]) \
-                    - req.prompt_blocks
-                bsz = self.model.cfg.block_size
-                lo, hi = req.prompt_blocks * bsz, \
-                    (req.prompt_blocks + gen_blocks) * bsz
-                # serve-stats count tokens up to and including the first
-                # EOS (the *request's* stop token): the rest of an EOS
-                # block is padding, not output
-                eos_id = req.params.eos_id
-                gen_tokens = int(decoding.count_gen_tokens(
-                    tokens[None], [req.prompt_blocks], [gen_blocks],
-                    eos_id=eos_id, block_size=bsz)[0])
-                hit_eos = bool((tokens[lo:hi] == eos_id).any())
-                # a live slot advances on every tick from admission to
-                # harvest, so its gen blocks map one-to-one onto the
-                # tick-version records starting at its admission point
-                a0 = self._slot_admit_abs[slot]
-                comp = Completion(
-                    uid=req.uid, tokens=tokens, steps=steps,
-                    prompt_blocks=req.prompt_blocks,
-                    gen_blocks=gen_blocks, gen_tokens=gen_tokens,
-                    denoise_steps=int(self._state.n_denoise[slot]),
-                    finish_reason="eos" if hit_eos else "length",
-                    admitted_tick=self._slot_admit_tick[slot],
-                    completed_tick=self.stats.ticks, params=req.params,
-                    param_version=self._slot_admit_version[slot],
-                    block_versions=np.asarray(
-                        self._tick_versions[a0:a0 + gen_blocks],
-                        np.int64))
-                out.append(comp)
-                self.tracer.end(("decode", req.uid),
-                                finish_reason=comp.finish_reason,
-                                gen_tokens=comp.gen_tokens,
-                                gen_blocks=comp.gen_blocks,
-                                denoise_steps=comp.denoise_steps,
-                                latency_ticks=comp.latency_ticks)
-                self._slot_req[slot] = None
-                evicted.append(slot)
-                if self.cache == "paged":
-                    freed_pages.extend(self._free_slot_pages(slot))
-                self.stats.completed += 1
-                self.stats.gen_tokens += gen_tokens
-                self.stats.denoise_steps += comp.denoise_steps
-            if evicted and self.cache == "paged":
+            if fin:
+                with self.tracer.span("scheduler.harvest.pull",
+                                      cat="scheduler", track="scheduler"):
+                    for slot in fin:
+                        out.append(self._harvest_slot(slot))
+                        if self.cache == "paged":
+                            freed_pages.extend(self._free_slot_pages(slot))
+            if fin and self.cache == "paged":
                 # reset the device table rows so the freed slots'
                 # idempotent re-commits dump into the null page, not
                 # into pages that may be re-allocated to other requests
                 # (shared prompt pages stay mapped in the *surviving*
                 # sharers' rows untouched)
                 table = self._state.table.at[
-                    jnp.asarray(evicted, jnp.int32)].set(-1)
+                    jnp.asarray(fin, jnp.int32)].set(-1)
                 self._state = dataclasses.replace(self._state,
                                                   table=table)
                 if freed_pages:
                     # exclusive pages only: wiping a still-shared page
                     # would blind the survivors to their own prompt
                     self._invalidate_pages(freed_pages)
-            hv.args["completed"] = len(evicted)
+            self._n_tick_evicted = len(fin)
+            if hv.recording:
+                hv.args["completed"] = len(fin)
+                if recorded:
+                    hv.args["forwards_needed"] = forwards_needed
         return out
+
+    def _harvest_slot(self, slot: int) -> Completion:
+        """Pull a finished slot's record to the host and free the slot
+        (its pages are the caller's to release)."""
+        req = self._slot_req[slot]
+        tokens = np.asarray(self._state.tokens[slot])
+        steps = np.asarray(self._state.steps[slot])
+        gen_blocks = int(self._state.blk[slot]) - req.prompt_blocks
+        bsz = self.model.cfg.block_size
+        lo, hi = req.prompt_blocks * bsz, \
+            (req.prompt_blocks + gen_blocks) * bsz
+        # serve-stats count tokens up to and including the first EOS
+        # (the *request's* stop token): the rest of an EOS block is
+        # padding, not output
+        eos_id = req.params.eos_id
+        gen_tokens = int(decoding.count_gen_tokens(
+            tokens[None], [req.prompt_blocks], [gen_blocks],
+            eos_id=eos_id, block_size=bsz)[0])
+        hit_eos = bool((tokens[lo:hi] == eos_id).any())
+        # a live slot advances on every tick from admission to harvest,
+        # so its gen blocks map one-to-one onto the tick-version records
+        # starting at its admission point
+        a0 = self._slot_admit_abs[slot]
+        comp = Completion(
+            uid=req.uid, tokens=tokens, steps=steps,
+            prompt_blocks=req.prompt_blocks,
+            gen_blocks=gen_blocks, gen_tokens=gen_tokens,
+            denoise_steps=int(self._state.n_denoise[slot]),
+            finish_reason="eos" if hit_eos else "length",
+            admitted_tick=self._slot_admit_tick[slot],
+            completed_tick=self.stats.ticks, params=req.params,
+            param_version=self._slot_admit_version[slot],
+            block_versions=np.asarray(
+                self._tick_versions[a0:a0 + gen_blocks], np.int64))
+        self.tracer.end(("decode", req.uid),
+                        finish_reason=comp.finish_reason,
+                        gen_tokens=comp.gen_tokens,
+                        gen_blocks=comp.gen_blocks,
+                        denoise_steps=comp.denoise_steps,
+                        latency_ticks=comp.latency_ticks)
+        self._slot_req[slot] = None
+        self.stats.completed += 1
+        self.stats.gen_tokens += gen_tokens
+        self.stats.denoise_steps += comp.denoise_steps
+        return comp
 
     def run(self, params) -> Iterator[Completion]:
         """Drive ticks until queue + slots drain, streaming completions."""

@@ -12,7 +12,6 @@ from repro.analysis.guards import TraceGuard
 from repro.core.block_diffusion import sft_loss
 from repro.core.masks import dirl_layout, sample_sft_noise
 from repro.kernels.ops import layout_tile_stats
-from repro.obs import profile
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Tracer
 from repro.optim import adamw
@@ -28,12 +27,14 @@ class SFTConfig:
 class SFTTrainer:
     """Supervised trainer over the fused NELBO step.
 
-    Observability: each ``train_step`` is bracketed by an obs span
-    (track ``"trainer"``; shared with the serving stack when a caller
-    passes an engine's tracer via ``tracer=``), and step wall times
-    aggregate into the ``dirl_trainer`` metrics namespace.  The span
-    interval includes the deliberate post-step sync, so
-    ``step_seconds`` keeps measuring the real device step.
+    Observability: each ``train_step`` is an ``sft.step`` span (track
+    ``"trainer"``; shared with the serving stack when a caller passes an
+    engine's tracer via ``tracer=``) over its five phases:
+    ``sft.batch_put`` (inputs to the device), ``sft.dispatch``,
+    ``sft.sync`` (the deliberate post-step sync), ``sft.metric_pull``
+    and ``sft.tile_stats``.  Step wall times — start to the end of the
+    sync, the real device step — aggregate into the ``dirl_trainer``
+    metrics namespace.
     """
 
     def __init__(self, model, opt_cfg: adamw.AdamWConfig, params, *,
@@ -79,22 +80,31 @@ class SFTTrainer:
                                 name="sft_step")
 
     def train_step(self, batch: dict, rng) -> dict:
-        with self.tracer.span("sft_step", cat="trainer",
-                              track="trainer") as sp:
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            with profile.annotate("sft_step"):
+        span = self.tracer.span
+        with span("sft.step", cat="trainer", track="trainer") as sp:
+            with span("sft.batch_put", cat="trainer", track="trainer"):
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            with span("sft.dispatch", cat="trainer", track="trainer"):
                 self.params, self.opt_state, metrics = self._step(
                     self.params, self.opt_state, batch, rng)
-            # deliberate: step_seconds must measure the real step, and
-            # metrics are pulled to host right below anyway
-            jax.block_until_ready(metrics["loss"])  # dirlint: ok(hot-sync)
-        self.step_seconds.append(sp.dur)
-        self._phase_seconds.labels(phase="train").observe(sp.dur)
-        self._steps_total.inc()
-        self._step_traces.set(self._step.n_traces)
-        out = {k: float(v) for k, v in metrics.items()}
-        out["step_traces"] = self._step.n_traces
-        out.update(self._tile_stats(batch, rng))
+            with span("sft.sync", cat="trainer", track="trainer") as sync:
+                # deliberate: step_seconds must measure the real step,
+                # and metrics are pulled to host right below anyway
+                jax.block_until_ready(metrics["loss"])  # dirlint: ok(hot-sync)
+            step_s = sync.t1 - sp.t0
+            self.step_seconds.append(step_s)
+            self._phase_seconds.labels(phase="train").observe(step_s)
+            self._steps_total.inc()
+            self._step_traces.set(self._step.n_traces)
+            with span("sft.metric_pull", cat="trainer", track="trainer"):
+                out = {k: float(v) for k, v in metrics.items()}
+            out["step_traces"] = self._step.n_traces
+            with span("sft.tile_stats", cat="trainer", track="trainer"):
+                out.update(self._tile_stats(batch, rng))
+            if sp.recording:
+                # B x L positions, padding included (a count of `valid`
+                # would be one more device op and pull inside the step)
+                sp.args["tokens"] = int(batch["tokens"].size)
         return out
 
     def _tile_stats(self, batch: dict, rng) -> dict:

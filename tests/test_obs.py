@@ -190,7 +190,8 @@ def test_lifecycle_completeness_under_deferral(setup):
     assert sched.tracer.n_open == 0           # every lifecycle closed
     spans = sched.tracer.snapshot()
     names = {sp.name for sp in spans}
-    assert {"tick", "admit", "advance", "harvest", "defer"} <= names
+    assert {"scheduler.tick", "scheduler.admit", "scheduler.advance",
+            "scheduler.harvest", "scheduler.defer"} <= names
     decode = {sp.args["uid"]: sp for sp in spans
               if sp.cat == "request" and sp.track.startswith("slot")}
     assert sorted(decode) == [0, 1, 2, 3]
@@ -201,7 +202,7 @@ def test_lifecycle_completeness_under_deferral(setup):
         assert sp.dur > 0
     queued = [sp for sp in spans if sp.track == "queue"]
     assert len(queued) == 4
-    defers = [sp for sp in spans if sp.name == "defer"]
+    defers = [sp for sp in spans if sp.name == "scheduler.defer"]
     assert all(sp.track == "scheduler" for sp in defers)
 
 
@@ -313,3 +314,240 @@ def test_jsonl_dump(tmp_path):
     assert len(lines) == n == 5
     assert all({"name", "track", "t0", "t1", "dur", "args"} <= set(ln)
                for ln in lines)
+
+
+# =========================================== spans under a profiler capture
+
+
+def _read_xplane(logdir):
+    """``bench.harness.trace.read_xplane``, loaded by path (the harness
+    is not a package of the program)."""
+    import importlib.util
+    import sys
+    from pathlib import Path
+    name = "_bench_harness_trace"
+    if name not in sys.modules:
+        path = Path(__file__).resolve().parents[1] / "bench" / "harness" \
+            / "trace.py"
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod         # its dataclasses look it up
+        spec.loader.exec_module(mod)
+    return sys.modules[name].read_xplane(logdir)
+
+
+class _AdvanceProbe:
+    """Stands in for the pool advance's guard: before each call notes
+    the live slots' committed blocks (the host mirror) and, after it,
+    each live row's denoise steps (``n_denoise`` pulled on both sides)."""
+
+    def __init__(self, sched):
+        self.sched, self.guard = sched, sched._advance
+        self.ctx, self.needed = [], []
+
+    @property
+    def n_traces(self):
+        return self.guard.n_traces
+
+    def __call__(self, params, st):
+        s = self.sched
+        live = [i for i, r in enumerate(s._slot_req) if r is not None]
+        self.ctx.append(sum(s._slot_blk[i] for i in live))
+        before = np.array(st.n_denoise)
+        out = self.guard(params, st)
+        used = np.array(out.n_denoise)[live] - before[live]
+        self.needed.append(1 + int(used.max()))
+        return out
+
+
+def _churn(sched, params):
+    """Six requests over two slots, budgets 1-3 blocks: three prompts
+    whose first block is shared, two members each (cold admissions,
+    suffix prefills and full hits, evictions every few ticks)."""
+    rng = np.random.default_rng(5)
+    head = rng.integers(4, 100, BSZ)
+    keys = jax.random.split(jax.random.PRNGKey(11), 6)
+    for i in range(6):
+        tail = rng.integers(4, 100, BSZ) if i % 2 == 0 else tail
+        prompt = np.concatenate([head, tail]).astype(np.int32)
+        sched.submit(prompt, 2, keys[i], max_new_blocks=1 + i % 3)
+    return {c.uid: c for c in sched.run(params)}
+
+
+def _sched(model):
+    return SlotScheduler(model, n_slots=2, max_len=MAX_LEN, s_max=4,
+                         mode="dynamic", tau=0.6, temperature=1.0,
+                         eos_id=1, cache="paged", kernel="pallas",
+                         prefix_cache=True, n_pages=9)
+
+
+@pytest.fixture(scope="module")
+def captured_run(setup, tmp_path_factory):
+    """The same churn run twice on fresh pools: with nothing recording,
+    and under a CPU ``jax.profiler`` capture (the tracer disabled)."""
+    from repro.obs import trace
+    model, params, _, _ = setup
+    plain = _sched(model)
+    comps_off = _churn(plain, params)
+    sched = _sched(model)
+    probe = _AdvanceProbe(sched)
+    sched._advance = probe
+    logdir = tmp_path_factory.mktemp("capture")
+    jax.profiler.start_trace(str(logdir))
+    try:
+        comps_on = _churn(sched, params)
+    finally:
+        jax.profiler.stop_trace()
+    return dict(sched=sched, plain=plain, probe=probe, logdir=logdir,
+                spans=trace.captured(), comps_off=comps_off,
+                comps_on=comps_on)
+
+
+def test_capture_records_scheduler_spans_with_parents(captured_run):
+    spans = captured_run["spans"]
+    by = {}
+    for sp in spans:
+        by.setdefault(sp.name, []).append(sp)
+    assert {"scheduler.tick", "scheduler.admit", "scheduler.alloc_pages",
+            "scheduler.advance", "scheduler.harvest",
+            "scheduler.harvest.sync", "scheduler.harvest.pull"} <= set(by)
+    ticks = by["scheduler.tick"]
+    assert all(sp.parent is None and sp.recording for sp in ticks)
+    for name in ("scheduler.admit", "scheduler.alloc_pages",
+                 "scheduler.advance", "scheduler.harvest"):
+        assert all(any(sp.parent is t for t in ticks) for sp in by[name])
+    for name in ("scheduler.harvest.sync", "scheduler.harvest.pull"):
+        assert all(sp.parent.name == "scheduler.harvest"
+                   for sp in by[name])
+    for name in ("scheduler.prefill", "scheduler.prefill_suffix"):
+        assert all(sp.parent.name == "scheduler.admit"
+                   for sp in by.get(name, []))
+    sched = captured_run["sched"]
+    assert len(ticks) == len(by["scheduler.advance"]) == sched.stats.ticks
+    admits = [sp for sp in by["scheduler.admit"]
+              if sp.args["path"] != "deferred"]
+    assert sorted(sp.args["uid"] for sp in admits) == list(range(6))
+    assert {"cold", "full_hit"} <= {sp.args["path"] for sp in admits}
+    assert all(sp.args["queue_s"] >= 0 for sp in admits)
+    assert sum(t.args["admitted"] for t in ticks) == 6
+    assert sum(t.args["evicted"] for t in ticks) == 6
+    assert sum(t.args["alloc_pages"] for t in ticks) == sum(
+        sp.args["pages"] for sp in by["scheduler.alloc_pages"])
+    # children close inside their parent
+    for sp in spans:
+        if sp.parent is not None:
+            assert sp.parent.t0 <= sp.t0 and sp.t1 <= sp.parent.t1
+
+
+def test_capture_counts_match_the_host_mirror(captured_run):
+    """Σ ctx_blocks is the live slots' committed blocks before each
+    advance; walked_blocks the whole table of every slot; each tick's
+    forwards_needed 1 + the most denoise steps a live row used."""
+    sched, probe = captured_run["sched"], captured_run["probe"]
+    adv = [sp for sp in captured_run["spans"]
+           if sp.name == "scheduler.advance"]
+    assert [sp.args["ctx_blocks"] for sp in adv] == probe.ctx
+    assert all(sp.args["walked_blocks"] == 2 * sched.n_blocks_total
+               and sp.args["forwards"] == 5 for sp in adv)
+    hv = [sp for sp in captured_run["spans"]
+          if sp.name == "scheduler.harvest"]
+    assert [sp.args["forwards_needed"] for sp in hv] == probe.needed
+    assert sched.stats.ctx_block_ticks == sum(probe.ctx)
+    assert sched.stats.denoise_forwards == 5 * sched.stats.ticks
+
+
+def test_capture_spans_sit_in_the_xplane_on_one_offset(captured_run):
+    import glob
+    from jax.profiler import ProfileData
+    host, _ = _read_xplane(captured_run["logdir"])
+    names = [n for _, _, n in host]
+    for name in ("scheduler.tick", "scheduler.admit",
+                 "scheduler.alloc_pages", "scheduler.advance",
+                 "scheduler.harvest"):
+        assert names.count(name) == sum(
+            sp.name == name for sp in captured_run["spans"]), name
+    (path,) = glob.glob(str(captured_run["logdir"] / "**" / "*.xplane.pb"),
+                        recursive=True)
+    offsets = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("scheduler."):
+                    stats = dict(ev.stats)
+                    offsets.append(stats["t_host_ns"] - int(ev.start_ns))
+    assert len(offsets) == sum(sp.name.startswith("scheduler.")
+                               for sp in captured_run["spans"])
+    assert max(offsets) - min(offsets) < 1_000_000
+
+
+def test_capture_leaves_tokens_and_traces_alone(captured_run):
+    off, on = captured_run["comps_off"], captured_run["comps_on"]
+    assert sorted(off) == sorted(on) == list(range(6))
+    for uid, c in off.items():
+        t = on[uid]
+        hi = (c.prompt_blocks + c.gen_blocks) * BSZ
+        assert c.gen_blocks == t.gen_blocks
+        np.testing.assert_array_equal(c.tokens[:hi], t.tokens[:hi])
+    assert captured_run["sched"].n_advance_traces == 1
+
+
+def test_nothing_records_outside_a_capture(captured_run, setup,
+                                           tmp_path):
+    """With the tracer disabled and no capture a span times itself and
+    records nothing; a new capture starts a new record."""
+    from repro.obs import trace
+    before = trace.captured()
+    assert before                               # the fixture's capture
+    plain = captured_run["plain"]
+    _, params, _, _ = setup
+    _churn(plain, params)
+    assert len(plain.tracer) == 0
+    tr = Tracer(enabled=False)
+    with tr.span("outside") as sp:
+        pass
+    assert not sp.recording and sp.dur >= 0
+    tr.instant("mark")
+    tr.begin("k", "life")
+    assert tr.end("k") is None and len(tr) == 0
+    after = trace.captured()
+    assert len(after) == len(before)
+    assert all(a is b for a, b in zip(after, before))
+    for name in ("first", "second"):
+        jax.profiler.start_trace(str(tmp_path / name))
+        try:
+            with tr.span(name) as sp:
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        assert sp.recording
+        assert [s.name for s in trace.captured()] == [name]
+    assert len(tr) == 0                         # the ring stays off
+
+
+def test_sft_step_phases_nest_under_the_step(tmp_path):
+    from repro.obs import trace
+    from repro.optim import adamw
+    from repro.sft.trainer import SFTTrainer
+    model = BlockDiffLM(CFG)
+    tr = SFTTrainer(model, adamw.AdamWConfig(lr=1e-3),
+                    model.init(jax.random.PRNGKey(3)))
+    B, L = 2, 32
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(4), (B, L),
+                                          4, 100),
+             "prompt_mask": np.arange(L)[None, :].repeat(B, 0) < 8,
+             "valid": np.ones((B, L), bool)}
+    tr.train_step(batch, jax.random.PRNGKey(5))          # compile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = tr.train_step(batch, jax.random.PRNGKey(6))
+    finally:
+        jax.profiler.stop_trace()
+    spans = trace.captured()
+    (step,) = [sp for sp in spans if sp.name == "sft.step"]
+    phases = [sp for sp in spans if sp.parent is step]
+    assert [sp.name for sp in sorted(phases, key=lambda s: s.t0)] == [
+        "sft.batch_put", "sft.dispatch", "sft.sync", "sft.metric_pull",
+        "sft.tile_stats"]
+    assert step.parent is None and step.args["tokens"] == B * L
+    assert tr.step_seconds[-1] <= step.dur
+    assert np.isfinite(out["loss"]) and len(tr.tracer) == 0

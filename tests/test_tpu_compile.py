@@ -17,6 +17,8 @@ import: only one process may load the TPU library, and the suite runs
 with several workers that all import this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -67,13 +69,25 @@ def _dims(name):
             cfg.block_size, cfg.sliding_window)
 
 
-def _compile(fn, shardings, *shapes):
+def _compile(fn, shardings, *shapes, kernels=()):
+    """Compile for the described chip; each of ``kernels`` (the
+    ``pallas_call`` names a trace shows) must be in the program."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=shardings)
             for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    _check_kernels(compiled.as_text(), kernels)
     return compiled
+
+
+def _check_kernels(text, kernels):
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    for name in kernels:
+        # the custom call itself carries the name, under autodiff
+        # with a prefix (jvp_, transpose_jvp_); a function of the same
+        # prefix may sit in the stack-frame table
+        call = re.compile(rf"%[\w.]*{name}[\w.]* = [^\n]*"
+                          r'custom_call_target="tpu_custom_call"')
+        assert call.search(text), f"no kernel call named {name!r}"
 
 
 @pytest.mark.parametrize("model", sorted(MODELS))
@@ -92,7 +106,7 @@ def test_paged_decode_compiles(model, one_chip, no_persistent_cache):
                  ((B, bsz, H, D), f32), ((P, Hkv, bsz, D), f32),
                  ((P, Hkv, bsz, D), f32), ((P, bsz), i32), ((B, K), i32),
                  ((B, bsz, Hkv, D), f32), ((B, bsz, Hkv, D), f32),
-                 ((B, bsz), i32), ((B,), i32))
+                 ((B, bsz), i32), ((B,), i32), kernels=("paged_decode",))
     # the device holds the output at least at its logical size (the
     # TPU layout may pad head dim 120 to the 128-lane tile)
     assert c.memory_analysis().output_size_in_bytes >= B * bsz * H * D * 4
@@ -115,7 +129,8 @@ def test_paged_prefill_compiles(model, Kp, one_chip, no_persistent_cache):
     _compile(prefill, one_chip,
              ((B, T, H, D), f32), ((P, Hkv, bsz, D), f32),
              ((P, Hkv, bsz, D), f32), ((P, bsz), i32), ((B, Kp), i32),
-             ((B, T, Hkv, D), f32), ((B, T, Hkv, D), f32), ((B, T), i32))
+             ((B, T, Hkv, D), f32), ((B, T, Hkv, D), f32), ((B, T), i32),
+             kernels=("paged_prefill",))
 
 
 @pytest.mark.parametrize("odd", [False, True], ids=["even", "odd"])
@@ -147,4 +162,5 @@ def test_block_diff_compiles(model, grad, odd, one_chip,
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for s in ((1, T, H, D), (1, T, Hkv, D), (1, T, Hkv, D))]
     text = jax.jit(fn).lower(*args, meta).compile().as_text()
-    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    _check_kernels(text, ("block_diff_fwd", "block_diff_dq",
+                          "block_diff_dkv") if grad else ("block_diff_fwd",))
