@@ -163,6 +163,20 @@ def layout_tile_stats(meta: SeqMeta, *, tq: int = 128, tk: int = 128,
     return tile_map_stats(build_tile_map(pm, pm, tq, tk, window=window))
 
 
+def layout_tile_counts(meta: SeqMeta, *, tq: int = 128, tk: int = 128,
+                       window: int | None = None) -> dict:
+    """Jittable twin of ``layout_tile_stats``: the same tile map, counted
+    on the device.  ``tiles_visited`` (> 0) and ``tiles_full`` (== 2) are
+    int32 scalars; ``tiles_total`` is static, a Python int."""
+    pm = pack_meta(meta)
+    L = pm.shape[1]
+    tile_map = build_tile_map(pm, pm, _pick_tile(L, tq), _pick_tile(L, tk),
+                              window=window)
+    return {"tiles_total": tile_map.size,
+            "tiles_visited": (tile_map > 0).sum(dtype=jnp.int32),
+            "tiles_full": (tile_map == 2).sum(dtype=jnp.int32)}
+
+
 # ---------------------------------------------------------------------------
 # structured jnp path (flash-style two-part merge, no Pallas)
 # ---------------------------------------------------------------------------

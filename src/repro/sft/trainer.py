@@ -11,10 +11,28 @@ import jax.numpy as jnp
 from repro.analysis.guards import TraceGuard
 from repro.core.block_diffusion import sft_loss
 from repro.core.masks import dirl_layout, sample_sft_noise
-from repro.kernels.ops import layout_tile_stats
+from repro.kernels.ops import layout_tile_counts
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Tracer
 from repro.optim import adamw
+
+
+# the step's raw tile counts: pulled with its metrics, turned into the
+# attn_tile_* fractions on the host, never returned themselves
+_TILE_COUNTS = ("tiles_total", "tiles_visited", "tiles_full")
+
+
+def _step_tile_counts(cfg, batch: dict, rng) -> dict:
+    """Tile counts of the step's own layout (same rng, so the same
+    sampled noise as ``sft_loss``), computed inside the jitted step."""
+    steps, _, _ = sample_sft_noise(
+        rng, batch["tokens"], batch["prompt_mask"], batch["valid"],
+        block_size=cfg.block_size)
+    _, meta, _ = dirl_layout(
+        batch["tokens"], steps, batch["valid"],
+        block_size=cfg.block_size, mask_token=cfg.resolved_mask_token,
+        noised=True)
+    return layout_tile_counts(meta)
 
 
 @dataclasses.dataclass
@@ -34,7 +52,9 @@ class SFTTrainer:
     ``sft.sync`` (the deliberate post-step sync), ``sft.metric_pull``
     and ``sft.tile_stats``.  Step wall times — start to the end of the
     sync, the real device step — aggregate into the ``dirl_trainer``
-    metrics namespace.
+    metrics namespace.  The dirl layout's attention tile map is counted
+    inside the jitted step and pulled with its metrics in one transfer;
+    nothing of the layout is replayed on the host.
     """
 
     def __init__(self, model, opt_cfg: adamw.AdamWConfig, params, *,
@@ -73,6 +93,8 @@ class SFTTrainer:
             params, opt_state, om = adamw.apply_updates(
                 opt_cfg, params, grads, opt_state)
             metrics = {**metrics, **om, "loss": loss}
+            if layout == "dirl":
+                metrics.update(_step_tile_counts(model.cfg, batch, rng))
             return params, opt_state, metrics
 
         # zero-retrace witness: fixed batch/rng shapes keep this at 1
@@ -97,30 +119,31 @@ class SFTTrainer:
             self._steps_total.inc()
             self._step_traces.set(self._step.n_traces)
             with span("sft.metric_pull", cat="trainer", track="trainer"):
-                out = {k: float(v) for k, v in metrics.items()}
+                # deliberate: one device-to-host transfer for every
+                # metric, tile counts included, after the sync above
+                host = jax.device_get(metrics)  # dirlint: ok(hot-sync)
+                counts = {k: host.pop(k) for k in _TILE_COUNTS
+                          if k in host}
+                out = {k: float(v) for k, v in host.items()}
             out["step_traces"] = self._step.n_traces
             with span("sft.tile_stats", cat="trainer", track="trainer"):
-                out.update(self._tile_stats(batch, rng))
+                out.update(self._tile_stats(counts))
             if sp.recording:
                 # B x L positions, padding included (a count of `valid`
                 # would be one more device op and pull inside the step)
                 sp.args["tokens"] = int(batch["tokens"].size)
         return out
 
-    def _tile_stats(self, batch: dict, rng) -> dict:
-        """Host-side replay of this step's layout (same rng, so the same
-        sampled noise) -> tile-map sparsity gauges."""
-        if self.layout != "dirl":
+    def _tile_stats(self, counts: dict) -> dict:
+        """The step's pulled tile counts -> tile-map sparsity gauges
+        (``tile_map_stats``'s arithmetic on the same integers)."""
+        if not counts:
             return {}
-        cfg = self.model.cfg
-        steps, _, _ = sample_sft_noise(
-            rng, batch["tokens"], batch["prompt_mask"], batch["valid"],
-            block_size=cfg.block_size)
-        _, meta, _ = dirl_layout(
-            batch["tokens"], steps, batch["valid"],
-            block_size=cfg.block_size, mask_token=cfg.resolved_mask_token,
-            noised=True)
-        stats = layout_tile_stats(meta)
+        total, visited, full = (int(counts[k]) for k in _TILE_COUNTS)
+        denom = max(total, 1)
+        stats = {"visit_fraction": visited / denom,
+                 "partial_fraction": (visited - full) / denom,
+                 "full_fraction": full / denom}
         out = {}
         for f, g in self._tile_gauges.items():
             g.set(stats[f])
